@@ -507,9 +507,12 @@ def run(*, smoke: bool = False, hosts: int = 2,
                  f"hosts={hosts}->{a_hosts}"))
 
     # -- jaxmesh over virtual devices (satellite: --virtual-devices) -------
-    # fresh interpreters: XLA fixes the device count at backend init
+    # fresh interpreters: XLA fixes the device count at backend init.  The
+    # virtual devices are CPU devices, so the child is pinned to the CPU: it
+    # must never contend for an accelerator this process may hold
     for n in (4, 8):
         env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = (
             env.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={n}").strip()

@@ -12,6 +12,7 @@ import numpy as np
 
 from repro.core import (Collect, Emit, Network, StencilEngine, build,
                         run_sequential, verify)
+from repro.launch.cache import enable_compile_cache
 
 EDGE3 = jnp.asarray([[-1, -1, -1], [-1, 8, -1], [-1, -1, -1]], jnp.float32)
 EDGE5 = jnp.asarray([[-1] * 5, [-1] * 5, [-1, -1, 24, -1, -1],
@@ -25,6 +26,7 @@ def main():
     ap.add_argument("--images", type=int, default=3)
     ap.add_argument("--pallas", action="store_true", default=True)
     args = ap.parse_args()
+    enable_compile_cache()
 
     rng = np.random.default_rng(0)
     # synthetic "photos": smooth gradients + a bright square to edge-detect

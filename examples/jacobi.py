@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.core import (Collect, Emit, MultiCoreEngine, Network, build,
                         rows, verify)
+from repro.launch.cache import enable_compile_cache
 
 
 def main():
@@ -24,6 +25,7 @@ def main():
     ap.add_argument("--systems", type=int, default=2)
     ap.add_argument("--tol", type=float, default=1e-7)
     args = ap.parse_args()
+    enable_compile_cache()
     n = args.n
 
     rng = np.random.default_rng(0)

@@ -1,7 +1,7 @@
 """Mandelbrot farm (paper §6.6): row bands fanned over workers, with the
 Pallas escape-time kernel as the Worker function.
 
-    PYTHONPATH=src python examples/mandelbrot.py [--width 280] [--pallas]
+    PYTHONPATH=src python examples/mandelbrot.py [--width 280]
     PYTHONPATH=src python examples/mandelbrot.py --hosts 2   # cluster mode
 
 ``--hosts N`` reruns the paper's capstone: the *same* declarative network is
@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import DataParallelCollect, build, run_sequential
+from repro.launch.cache import enable_compile_cache
 
 CHARS = " .:-=+*#%@"
 
@@ -88,11 +89,9 @@ def main():
                          "2, let the deployment recover (respawn + epoch "
                          "bump + replay of the lost chunks) and print the "
                          "recovery section of the cluster report")
-    ap.add_argument("--pallas", action="store_true",
-                    help="use the Pallas kernel (interpret mode — slower "
-                         "on CPU, exact on TPU)")
     ap.add_argument("--ascii", action="store_true", default=True)
     args = ap.parse_args()
+    enable_compile_cache()
 
     H, W = args.height, args.width
     factory = (make_net, (W, H, args.bands, args.iters))
@@ -180,12 +179,11 @@ def main():
                           for c in range(W)))
 
     if not args.hosts:
-        # Pallas kernel cross-check on the full image (interpret mode)
+        # Pallas kernel cross-check on the full image (interpreted on CPU)
         from repro.kernels.mandelbrot import ops as mb_ops
         delta = 3.0 / W
         full = mb_ops.mandelbrot(H, W, x0=-2.2, y0=-1.15, pixel_delta=delta,
-                                 max_iterations=args.iters,
-                                 interpret=True)
+                                 max_iterations=args.iters)
         print(f"pallas kernel == farm image: "
               f"{bool((np.asarray(full) == img).all())}")
 

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import DataParallelCollect, build, csp, run_sequential
+from repro.launch.cache import enable_compile_cache
 
 INSTANCES = 256
 ITERATIONS = 10_000
@@ -46,6 +47,7 @@ def finalise(total_within):
 
 
 def main():
+    enable_compile_cache()
     # the declarative network (paper Listing 2 — one pattern invocation)
     net = DataParallelCollect(
         create=create, function=get_within, collector=collector,

@@ -12,6 +12,7 @@ import time
 import jax
 
 from repro.configs import get_config
+from repro.launch.cache import enable_compile_cache
 from repro.models import Model
 from repro.serve import FarmScheduler, Request
 
@@ -22,6 +23,7 @@ def main():
     ap.add_argument("--requests", type=int, default=10)
     ap.add_argument("--slots", type=int, default=4)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=True)
     model = Model(cfg)

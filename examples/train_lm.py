@@ -21,6 +21,7 @@ import jax
 
 from repro.configs.base import ModelConfig
 from repro.data import SyntheticLM
+from repro.launch.cache import enable_compile_cache
 from repro.models import Model
 from repro.train import (AdamW, Checkpointer, FaultInjector,
                          FaultTolerantRunner, cosine_warmup, make_train_step)
@@ -45,6 +46,7 @@ def main():
     ap.add_argument("--grad-accum", type=int, default=1)
     ap.add_argument("--inject-failure", action="store_true", default=True)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = ModelConfig(name=f"lm-{args.size}", family="dense",
                       qkv_bias=False, tied_embeddings=True,

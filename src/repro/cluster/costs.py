@@ -224,7 +224,6 @@ def calibrate(net, *, instances: Optional[int] = None,
     out.microbatch_size = microbatch_size
     out.seed = seed
     import jax
-    from repro.core._jax_compat import cost_analysis_dict
 
     total_wall = total_flops = 0.0
     for name, xs in ex.captured.items():
@@ -243,8 +242,8 @@ def calibrate(net, *, instances: Optional[int] = None,
             best = min(best, _time.perf_counter() - t0)
         result = fn(*xs)
         flops = bytes_accessed = 0.0
-        try:  # HLO prior — best effort, version-guarded
-            ca = cost_analysis_dict(fn.lower(*xs).compile())
+        try:  # HLO prior — best effort
+            ca = fn.lower(*xs).compile().cost_analysis()
             flops = float(ca.get("flops") or 0.0)
             bytes_accessed = float(ca.get("bytes accessed") or 0.0)
         except Exception:

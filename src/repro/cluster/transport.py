@@ -109,6 +109,31 @@ class TransportError(NetworkError):
     """A cut channel failed (peer died, timeout, protocol violation)."""
 
 
+def _spawn_context():
+    """The ``spawn`` context process hosts (``pipe``/``shm``) start from.
+
+    Each spawned host initialises a JAX runtime of its own, and a TPU
+    belongs to one process at a time.  A host process started while this
+    process holds the chip cannot open it: on a v5e host it fails at
+    backend start on libtpu's lock file, and where JAX may pick its backend
+    it falls back to the CPU, whose work would then be reported as the
+    deployment's.  Process hosts are therefore CPU hosts, refused here
+    whenever this process's backend is not the CPU."""
+    import jax
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise NetworkError(
+            f"process-host transports ('pipe', 'shm') spawn one JAX runtime "
+            f"per host, and this process holds the {backend} device, which "
+            "no host process can open; deploy with transport='inprocess' "
+            "or 'jaxmesh' (hosts are threads on this process's devices), or "
+            "run with JAX_PLATFORMS=cpu for CPU hosts")
+    import multiprocessing
+    # spawn: never fork a live JAX runtime (XLA thread pools do not survive
+    # fork); children rebuild the network from a factory
+    return multiprocessing.get_context("spawn")
+
+
 def encode(value):
     """Pytree of arrays -> picklable numpy pytree (identity for markers)."""
     if isinstance(value, str):
@@ -818,12 +843,7 @@ class MultiProcessPipe(_QueueTransport):
 
     def __init__(self, ctx=None):
         super().__init__()
-        if ctx is None:
-            import multiprocessing
-            # spawn: never fork a live JAX runtime (XLA thread pools do not
-            # survive fork); children rebuild the network from a factory
-            ctx = multiprocessing.get_context("spawn")
-        self.ctx = ctx
+        self.ctx = _spawn_context() if ctx is None else ctx
 
     def _new_queue(self, chan, capacities):
         return self.ctx.Queue(maxsize=self._capacity(capacities, chan))
@@ -1267,10 +1287,7 @@ class SharedMemoryRing(_ShmOps, ChannelTransport):
 
     def __init__(self, ctx=None, slot_bytes: int = 1 << 20,
                  double_buffer: bool = False):
-        if ctx is None:
-            import multiprocessing
-            ctx = multiprocessing.get_context("spawn")
-        self.ctx = ctx
+        self.ctx = _spawn_context() if ctx is None else ctx
         self.slot_bytes = slot_bytes
         # 2× physical slots per ring (same logical CSP capacity): a producer
         # packs the next slot while the consumer is still unpacking the
@@ -1476,9 +1493,9 @@ class JaxMesh(InProcess):
     """Cross-host channels over one JAX mesh: host *h* owns submesh *h*
     (``device_split``), and a send materialises the chunk on the consumer's
     submesh.  When the consumer's first stage is a jitted Worker/Engine, the
-    placement is instead folded into that stage jit (the runtime seeds the
-    executor's ``_in_spec``), so the cross-host reshard and the stage body
-    are one compiled program — mesh collectives, not eager copies."""
+    placement is instead folded into that stage jit as its input sharding
+    (the runtime seeds the executor's ``_in_spec``), so the cross-host
+    transfer and the stage body are one dispatch, not an eager copy first."""
 
     name = "jaxmesh"
 

@@ -404,8 +404,7 @@ class CompiledNetwork:
             wall = time.monotonic() - t0
             flops = bytes_ = None
             try:
-                from ._jax_compat import cost_analysis_dict
-                ca = cost_analysis_dict(jfn.lower(*args).compile())
+                ca = jfn.lower(*args).compile().cost_analysis()
                 flops = ca.get("flops")
                 bytes_ = ca.get("bytes accessed")
             except Exception:  # cost analysis is best-effort

@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ._jax_compat import shard_map
 from .dataflow import Kind, ProcessDef
 
 __all__ = ["rows", "IterativeEngine", "Stencil", "MultiCoreEngine",
@@ -83,7 +82,7 @@ class IterativeEngine:
                 return self.calculation(part)
 
             spec_in = jax.tree_util.tree_map(lambda _: P(), state)
-            upd = shard_map(
+            upd = jax.shard_map(
                 shard_calc, mesh=mesh,
                 in_specs=(spec_in,), out_specs=P(axis),
             )(state)
@@ -132,7 +131,7 @@ class Stencil:
     op: Optional[Callable] = None
     axis: Optional[str] = None
     nodes: int = 1
-    use_pallas: bool = False  # Pallas path (interpret on CPU) vs pure jnp
+    use_pallas: bool = False  # Pallas kernel vs pure jnp
 
     def __post_init__(self) -> None:
         if (self.kernel is None) == (self.op is None):
@@ -141,7 +140,7 @@ class Stencil:
     def _conv_local(self, img: jax.Array) -> jax.Array:
         if self.use_pallas:
             from repro.kernels.stencil import ops as stencil_ops
-            return stencil_ops.stencil2d(img, self.kernel, interpret=True)
+            return stencil_ops.stencil2d(img, self.kernel)
         from repro.kernels.stencil import ref as stencil_ref
         return stencil_ref.stencil2d(img, self.kernel)
 
@@ -166,7 +165,7 @@ class Stencil:
             out = self._conv_local(padded)
             return out[halo:-halo] if halo else out
 
-        return shard_map(
+        return jax.shard_map(
             shard_conv, mesh=mesh, in_specs=(P(axis),), out_specs=P(axis),
         )(img)
 

@@ -412,11 +412,10 @@ class StreamExecutor:
         # CPU has no buffer donation — requesting it only buys a UserWarning
         # per stage per chunk
         self._can_donate = jax.default_backend() != "cpu"
-        # mesh execution: fold each stage's input sharding constraint INTO its
-        # jit (with_sharding_constraint inside the traced program) instead of
-        # an eager per-chunk device_put between stages — the constraint and
-        # the compute compile to one program, so XLA overlaps the reshard with
-        # the stage body.  Maps stage name -> PartitionSpec of its input.
+        # mesh execution: fold each stage's input placement INTO its jit (as
+        # the jit's input sharding) instead of an eager per-chunk device_put
+        # between stages — the placement and the compute are one dispatch.
+        # Maps stage name -> PartitionSpec of its input.
         self._in_spec: dict = {}
         if self.cn.mesh is not None:
             P = jax.sharding.PartitionSpec
@@ -470,22 +469,18 @@ class StreamExecutor:
         key = (name, donate)
         if key not in self._jits:
             self._record_build(name)
-            fn = self._stage_fn(name)
             spec = self._in_spec.get(name)
-            if spec is not None:  # sharding constraint folded into the jit
-                sharding = jax.sharding.NamedSharding(self.cn.mesh, spec)
-
-                def fn(x, _inner=fn, _s=sharding):
-                    x = jax.tree_util.tree_map(
-                        lambda l: jax.lax.with_sharding_constraint(l, _s)
-                        if hasattr(l, "ndim") and l.ndim > 0 else l, x)
-                    return _inner(x)
-
+            # the input placement is folded into the jit as its input
+            # sharding: jit moves an input committed to another device (a
+            # chunk from another host's chip), which a sharding constraint
+            # inside the program may not
+            kw = ({} if spec is None else {"in_shardings": (
+                jax.sharding.NamedSharding(self.cn.mesh, spec),)})
             # the counter body executes only while jax TRACES (cache miss /
             # new shape): a warm deployment must never tick it again
             self._jits[key] = jax.jit(
-                self._counted(fn, self._stage_label(name)),
-                donate_argnums=(0,) if donate else ())
+                self._counted(self._stage_fn(name), self._stage_label(name)),
+                donate_argnums=(0,) if donate else (), **kw)
         return self._jits[key]
 
     def _counted(self, fn, label):

@@ -2,8 +2,8 @@
 
 Each kernel subpackage is ``kernel.py`` (pl.pallas_call + BlockSpec VMEM
 tiling), ``ops.py`` (padded/jit public wrapper), ``ref.py`` (pure-jnp
-oracle).  On this CPU-only container kernels are validated with
-``interpret=True``; on TPU pass ``interpret=False``.
+oracle).  Whether a kernel is compiled by Mosaic or run in the Pallas
+interpreter is decided here, from the platform, by :func:`use_interpreter`.
 
   stencil/          2D image/Jacobi stencil (paper §6.4 StencilEngine hotspot)
   flash_attention/  causal GQA flash attention (LM prefill/train hotspot)
@@ -11,3 +11,12 @@ oracle).  On this CPU-only container kernels are validated with
   mandelbrot/       escape-time fractal (paper §6.6 farm workload)
   moe_gmm/          grouped expert matmul (MoE archs)
 """
+
+import jax
+
+
+def use_interpreter() -> bool:
+    """True when JAX's default backend is the CPU, which has no Mosaic
+    compiler: the kernels then run in the Pallas interpreter.  On a TPU they
+    are always compiled; there is no option to interpret them there."""
+    return jax.default_backend() == "cpu"

@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import use_interpreter
+
 NEG_INF = -1e30
 
 
@@ -93,12 +95,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "scale", "block_q", "block_k", "group",
-                     "q_real", "kv_real", "interpret"))
+                     "q_real", "kv_real"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, scale: float | None = None,
                     block_q: int = 128, block_k: int = 128, group: int = 1,
-                    q_real: int | None = None, kv_real: int | None = None,
-                    interpret: bool = False) -> jax.Array:
+                    q_real: int | None = None,
+                    kv_real: int | None = None) -> jax.Array:
     """q: (BH, Sq, D); k, v: (BK, Sk, D) with BH == BK * group.
 
     Heads are pre-folded into the batch dim by ops.py; ``group`` = H // K.
@@ -135,5 +137,5 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=use_interpreter(),
     )(q, k, v)

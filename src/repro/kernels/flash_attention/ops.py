@@ -11,7 +11,7 @@ from .kernel import flash_attention
 
 def mha(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
         scale: float | None = None, block_q: int = 128, block_k: int = 128,
-        interpret: bool = True, use_pallas: bool = True) -> jax.Array:
+        use_pallas: bool = True) -> jax.Array:
     """q: (B, H, Sq, D); k, v: (B, K, Sk, D).  Same contract as ref.mha."""
     if not use_pallas:
         return ref.mha(q, k, v, causal=causal, scale=scale)
@@ -34,8 +34,7 @@ def mha(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
     # padded q rows (at the end) are cropped from the output below.
     out = flash_attention(qf, kf, vf, causal=causal, scale=scale,
                           block_q=bq, block_k=bk, group=group,
-                          q_real=Sq, kv_real=Sk,
-                          interpret=interpret)
+                          q_real=Sq, kv_real=Sk)
     return out[:, :Sq, :].reshape(B, H, Sq, D)
 
 

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import use_interpreter
+
 
 def _gmm_kernel(t2e_ref, x_ref, w_ref, o_ref):
     del t2e_ref  # consumed by the index_map only
@@ -31,10 +33,9 @@ def _gmm_kernel(t2e_ref, x_ref, w_ref, o_ref):
         preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("tile_m", "tile_f", "interpret"))
+@functools.partial(jax.jit, static_argnames=("tile_m", "tile_f"))
 def gmm(x: jax.Array, tile_expert: jax.Array, w: jax.Array, *,
-        tile_m: int = 128, tile_f: int = 512,
-        interpret: bool = False) -> jax.Array:
+        tile_m: int = 128, tile_f: int = 512) -> jax.Array:
     """x: (T, D) sorted+group-padded tokens (T % tile_m == 0);
     tile_expert: (T // tile_m,) int32 expert id per row tile;
     w: (E, D, F) with F % tile_f == 0.  Returns (T, F)."""
@@ -56,5 +57,5 @@ def gmm(x: jax.Array, tile_expert: jax.Array, w: jax.Array, *,
                                    lambda i, j, t2e: (i, j)),
         ),
         out_shape=jax.ShapeDtypeStruct((T, F), x.dtype),
-        interpret=interpret,
+        interpret=use_interpreter(),
     )(tile_expert.astype(jnp.int32), x, w)

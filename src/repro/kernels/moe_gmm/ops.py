@@ -45,7 +45,7 @@ def sort_by_expert(x: jax.Array, expert_of: jax.Array, n_expert: int,
 
 
 def moe_apply(x: jax.Array, expert_of: jax.Array, w: jax.Array, *,
-              tile_m: int = 128, tile_f: int = 512, interpret: bool = True,
+              tile_m: int = 128, tile_f: int = 512,
               use_pallas: bool = True) -> jax.Array:
     """Apply per-token expert matmul.  x: (T, D); w: (E, D, F) → (T, F)."""
     if not use_pallas:
@@ -57,8 +57,7 @@ def moe_apply(x: jax.Array, expert_of: jax.Array, w: jax.Array, *,
     tf = max(tf, 1)
     x_p, tile_expert, (order, slot), _ = sort_by_expert(
         x, expert_of, E, tile_m)
-    y_p = gmm_kernel(x_p, tile_expert, w, tile_m=tile_m, tile_f=tf,
-                     interpret=interpret)
+    y_p = gmm_kernel(x_p, tile_expert, w, tile_m=tile_m, tile_f=tf)
     y_sorted = y_p[slot]  # back to sorted-token order
     T = x.shape[0]
     y = jnp.zeros((T, F), y_p.dtype).at[order].set(y_sorted)

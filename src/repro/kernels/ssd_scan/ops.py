@@ -9,8 +9,8 @@ from . import ref
 from .kernel import ssd_scan
 
 
-def ssd(x, dt, A, B, C, *, chunk: int = 64, interpret: bool = True,
-        use_pallas: bool = False, return_state: bool = False):
+def ssd(x, dt, A, B, C, *, chunk: int = 64, use_pallas: bool = False,
+        return_state: bool = False):
     """Multi-head SSD.
 
     x: (batch, S, H, P); dt: (batch, S, H); A: (H,);
@@ -33,7 +33,7 @@ def ssd(x, dt, A, B, C, *, chunk: int = 64, interpret: bool = True,
     ch = chunk if S % chunk == 0 else S
     if use_pallas and not return_state:
         a_log = dtf * Af[:, None]
-        y = ssd_scan(xf, dtf, a_log, Bf, Cf, chunk=ch, interpret=interpret)
+        y = ssd_scan(xf, dtf, a_log, Bf, Cf, chunk=ch)
         hT = None
     else:
         y, hT = ref.ssd_chunked(xf, dtf, Af, Bf, Cf, chunk=ch)

@@ -27,6 +27,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels import use_interpreter
+
 
 def _stencil_kernel(prev_ref, cur_ref, next_ref, out_ref, *, taps: tuple,
                     halo: int, tile_h: int):
@@ -53,9 +55,9 @@ def _stencil_kernel(prev_ref, cur_ref, next_ref, out_ref, *, taps: tuple,
     out_ref[...] = out.astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("taps", "tile_h", "interpret"))
-def stencil2d_pallas(img: jax.Array, *, taps: tuple, tile_h: int = 128,
-                     interpret: bool = False) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("taps", "tile_h"))
+def stencil2d_pallas(img: jax.Array, *, taps: tuple,
+                     tile_h: int = 128) -> jax.Array:
     """``img`` (H, W) with H % tile_h == 0; ``taps`` a tuple-of-tuples kernel."""
     H, W = img.shape
     k = len(taps)
@@ -76,7 +78,7 @@ def stencil2d_pallas(img: jax.Array, *, taps: tuple, tile_h: int = 128,
         in_specs=[bs_prev, bs, bs_next],
         out_specs=bs,
         out_shape=jax.ShapeDtypeStruct((H, W), img.dtype),
-        interpret=interpret,
+        interpret=use_interpreter(),
     )(img, img, img)
 
 

@@ -14,6 +14,7 @@ from __future__ import annotations
 import argparse
 
 from ._common import add_cluster_flags, apply_runtime_env, autoscale_policy
+from .cache import enable_compile_cache
 
 
 # module-level factories: the pipe transport spawns fresh interpreters that
@@ -99,6 +100,7 @@ def main():
                          "per flush (0 = per-record sends, the default)")
     args = ap.parse_args()
     apply_runtime_env(args)
+    enable_compile_cache()
 
     import time
 

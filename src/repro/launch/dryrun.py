@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCHS, SHAPES_BY_NAME, applicable, get_config
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh, serve_rules, train_rules
 from repro.models import Model
 from repro.parallel import sharding as shlib
@@ -281,8 +282,7 @@ def _cost_probe(cfg, shape, mesh, rules, grad_accum: int = 1) -> dict:
         c = _with_layers(dataclasses.replace(cfg, scan_layers=False), L)
         compiled, _ = _compile_variant(c, shape, mesh, rules,
                                        grad_accum=grad_accum)
-        from repro.core._jax_compat import cost_analysis_dict
-        ca = cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         coll, kinds = _collective_bytes(compiled.as_text())
         metrics.append({"flops": float(ca.get("flops", 0.0)),
                         "bytes": float(ca.get("bytes accessed", 0.0)),
@@ -411,6 +411,7 @@ def main():
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--force", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.all or args.arch is None:
         run_all(args.mesh, only_arch=args.arch, only_shape=args.shape,
                 force=args.force)

@@ -25,15 +25,9 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh (tests, examples, elastic re-mesh).
-
-    ``axis_types`` only exists from jax 0.5 on; older jax defaults every
-    axis to Auto anyway, so omit it there.
-    """
-    kw = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(tuple(shape), tuple(axes), **kw)
+    """Arbitrary mesh (tests, examples, elastic re-mesh)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def train_rules(seq_shard: bool = False, fsdp: bool = False,

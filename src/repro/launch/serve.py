@@ -22,6 +22,7 @@ import time
 
 from ._common import (add_cluster_flags, add_model_flags, apply_runtime_env,
                       autoscale_policy)
+from .cache import enable_compile_cache
 
 
 def _pct(xs: list, q: float) -> float:
@@ -46,6 +47,7 @@ def main():
                     help="arrival-trace seed")
     args = ap.parse_args()
     apply_runtime_env(args)
+    enable_compile_cache()
 
     import jax
 

@@ -15,6 +15,8 @@ import argparse
 import json
 import os
 
+from .cache import enable_compile_cache
+
 
 def main():
     ap = argparse.ArgumentParser()
@@ -35,6 +37,7 @@ def main():
     ap.add_argument("--seq-shard", action="store_true",
                     help="sequence-parallel activations (perf lever)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.mesh == "multi":
         os.environ.setdefault(

@@ -130,7 +130,7 @@ def _sdpa(q, k, v, *, causal: bool, use_pallas: bool,
     vt = jnp.swapaxes(v, 1, 2)
     if use_pallas:
         from repro.kernels.flash_attention import ops as fops
-        ot = fops.mha(qt, kt, vt, causal=causal, interpret=True)
+        ot = fops.mha(qt, kt, vt, causal=causal)
     elif attn_chunk:
         from repro.kernels.flash_attention import ref as fref
         ot = fref.mha_chunked(qt, kt, vt, causal=causal, chunk=attn_chunk)

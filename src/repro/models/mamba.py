@@ -85,7 +85,7 @@ def mamba_apply(p: dict, cfg, x: jax.Array, *, return_state: bool = False):
                          + p["dt_bias"].astype(jnp.float32))
     A = -jnp.exp(p["A_log"].astype(jnp.float32))  # (H,)
     res = ssd_ops.ssd(xs, dt, A, Bm, Cm, chunk=cfg.ssm.chunk,
-                      use_pallas=cfg.use_pallas, interpret=True,
+                      use_pallas=cfg.use_pallas,
                       return_state=return_state)
     y, hT = res if return_state else (res, None)
     y = y + p["D_skip"].astype(jnp.float32)[None, None, :, None] * xs

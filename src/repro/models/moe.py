@@ -117,7 +117,7 @@ def moe_apply_ragged(p: dict, cfg, x: jax.Array):
     xs_rep = jnp.repeat(xf, k, axis=0)  # (T·k, D): one row per choice
     eo = topi.reshape(-1)
     w = p["experts"]
-    kw = dict(tile_m=128, interpret=True, use_pallas=cfg.use_pallas)
+    kw = dict(tile_m=128, use_pallas=cfg.use_pallas)
     g = gmm_ops.moe_apply(xs_rep, eo, w["gate"].astype(cd), **kw)
     u = gmm_ops.moe_apply(xs_rep, eo, w["up"].astype(cd), **kw)
     h = jax.nn.silu(g) * u

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core._jax_compat import pcast, shard_map
 from repro.core.stream import stack_microbatches
 
 __all__ = ["pipeline_forward", "split_stages"]
@@ -76,16 +75,16 @@ def pipeline_forward(block_fn: Callable, stage_params, x, *, mesh,
             return recv_next, out
 
         # carries are stage-varying (ppermute/axis_index outputs): mark them
-        out0 = pcast(jnp.zeros_like(x_all), (stage_axis,),
+        out0 = jax.lax.pcast(jnp.zeros_like(x_all), (stage_axis,),
                              to="varying")
-        recv0 = pcast(jnp.zeros_like(x_all[0]), (stage_axis,),
+        recv0 = jax.lax.pcast(jnp.zeros_like(x_all[0]), (stage_axis,),
                               to="varying")
         _, out = jax.lax.fori_loop(0, n_micro + n_stages - 1, step,
                                    (recv0, out0))
         return out[None]  # (1, n_micro, mb, S, D) per stage
 
     spec_p = jax.tree_util.tree_map(lambda _: P(stage_axis), stage_params)
-    out_all = shard_map(
+    out_all = jax.shard_map(
         staged, mesh=mesh,
         in_specs=(spec_p, P()),
         out_specs=P(stage_axis),

@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (Collect, DataParallelCollect, Emit,
                         GroupOfPipelineCollects, Network, OnePipelineCollect,

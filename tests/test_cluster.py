@@ -545,6 +545,22 @@ class TestMultiProcessPipe:
             run_cluster(_farm(), instances=4, hosts=2, transport="pipe",
                         microbatch_size=2)
 
+    @pytest.mark.parametrize("kind", ["pipe", "shm"])
+    def test_process_hosts_refused_on_an_accelerator(self, monkeypatch,
+                                                     kind):
+        """A process holding an accelerator spawns no host process that
+        would contend for it (or quietly compute on the CPU instead): the
+        process-host transports refuse and name the thread-host ones."""
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(NetworkError,
+                           match="holds the tpu device.*'inprocess'.*"
+                                 "'jaxmesh'"):
+            run_cluster(_farm_factory(4, 2), instances=4, hosts=2,
+                        transport=kind, microbatch_size=2,
+                        factory=(_farm_factory, (4, 2)))
+        for thread_hosts in ("inprocess", "jaxmesh"):
+            make_transport(thread_hosts).close()
+
     def test_encode_roundtrip(self):
         from repro.cluster.transport import decode, encode
         tree = (jnp.asarray([1.0, 2.0]), {"a": jnp.arange(3)})

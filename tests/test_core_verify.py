@@ -2,7 +2,7 @@
 network the pattern combinators can build and refuses each illegal shape."""
 
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (Collect, DataParallelCollect, Emit,
                         GroupOfPipelineCollects, Network, NetworkError,

@@ -1,6 +1,6 @@
 """FDR4-lite model checking (paper §4.6, §6.1.1, CSPm Definitions 1–7)."""
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (DataParallelCollect, GroupOfPipelineCollects,
                         Network, OnePipelineCollect,

@@ -61,12 +61,11 @@ def test_int8_ring_allreduce_and_error_feedback():
     def red(gl, el):
         r, e = ring_allreduce_int8(gl[0], "dp", 8, error=el[0])
         return r[None], e[None]
-    from repro.core._jax_compat import shard_map
-    red0j = jax.jit(shard_map(red0, mesh=mesh, in_specs=(P("dp"),),
-                              out_specs=(P("dp"), P("dp"))))
-    redj = jax.jit(shard_map(red, mesh=mesh,
-                             in_specs=(P("dp"), P("dp")),
-                             out_specs=(P("dp"), P("dp"))))
+    red0j = jax.jit(jax.shard_map(red0, mesh=mesh, in_specs=(P("dp"),),
+                                  out_specs=(P("dp"), P("dp"))))
+    redj = jax.jit(jax.shard_map(red, mesh=mesh,
+                                 in_specs=(P("dp"), P("dp")),
+                                 out_specs=(P("dp"), P("dp"))))
     exact = jnp.sum(g, axis=0)
     r1, err = red0j(g)
     rel1 = float(jnp.max(jnp.abs(r1[0] - exact)) / jnp.max(jnp.abs(exact)))
@@ -104,6 +103,44 @@ def test_compiled_farm_uses_devices():
     val, has_ar = out.split()
     assert float(val) == sum(i * i for i in range(64))
     assert has_ar == "True"  # the Collect fold psums across shards
+
+
+def test_jaxmesh_hosts_compute_on_their_own_devices():
+    """Four jaxmesh hosts on four devices: a chunk committed to its
+    producer's device enters the consumer's stage jit (whose placement is
+    folded in) and is computed on the consumer's device, bit-identical to
+    the farm on one device."""
+    out = _run("""
+    import jax, numpy as np
+    from repro.cluster import ClusterDeployment, partition
+    from repro.cluster.transport import JaxMesh
+    from repro.core import DataParallelCollect, build
+    import jax.numpy as jnp
+    net = DataParallelCollect(
+        create=lambda i: jnp.asarray(float(i)),
+        function=lambda x: jnp.sin(x) * x,
+        collector=lambda acc, x: acc + [float(x)], init=[],
+        workers=4, name="farm")
+    plan = partition(net, assignment={"emit": 0, "ofa": 1, "group": 2,
+                                      "afo": 3, "collect": 3})
+    sent = {}
+
+    class Probe(JaxMesh):
+        def _send_transform(self, chan, value):
+            for leaf in jax.tree_util.tree_leaves(value):
+                sent.setdefault(plan.assignment[chan[0]], set()).update(
+                    d.id for d in leaf.devices())
+            return super()._send_transform(chan, value)
+
+    with ClusterDeployment(net, plan=plan, transport=Probe(devices=jax.devices()[:4]),
+                           microbatch_size=4) as dep:
+        got = dep.run(instances=16)["collect"]
+    want = build(net).run(instances=16)["collect"]
+    print(got == want, sorted((h, sorted(d)) for h, d in sent.items()))
+    """, devices=4)
+    same, placed = out.strip().split(" ", 1)
+    assert same == "True"
+    assert placed == "[(0, [0]), (1, [1]), (2, [2])]"
 
 
 def test_reduced_model_dryrun_small_mesh():

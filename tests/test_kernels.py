@@ -1,4 +1,4 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (interpret mode).
+"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (interpreted on CPU).
 
 Every kernel gets (a) a parametrised sweep over shapes/dtypes and (b) a
 hypothesis property test on the contract that matters (e.g. causality)."""
@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.flash_attention import ops as fa_ops, ref as fa_ref
 from repro.kernels.mandelbrot import ops as mb_ops, ref as mb_ref
@@ -28,7 +28,7 @@ class TestStencil:
     def test_vs_ref(self, rng, hw, k, dtype):
         img = jnp.asarray(rng.normal(size=hw), dtype)
         kern = jnp.asarray(rng.normal(size=(k, k)).astype(np.float32))
-        out = st_ops.stencil2d(img, kern, tile_h=32, interpret=True)
+        out = st_ops.stencil2d(img, kern, tile_h=32)
         refv = st_ref.stencil2d(img, kern)
         tol = 2e-2 if dtype == jnp.bfloat16 else 1e-4
         np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -38,7 +38,7 @@ class TestStencil:
     def test_identity_kernel(self, rng):
         img = jnp.asarray(rng.normal(size=(32, 32)).astype(np.float32))
         k = jnp.zeros((3, 3)).at[1, 1].set(1.0)
-        out = st_ops.stencil2d(img, k, interpret=True)
+        out = st_ops.stencil2d(img, k)
         np.testing.assert_allclose(np.asarray(out), np.asarray(img),
                                    rtol=1e-6)
 
@@ -59,8 +59,7 @@ class TestFlashAttention:
         q = jnp.asarray(rng.normal(size=(B, H, S, D)).astype(np.float32)) * .3
         k = jnp.asarray(rng.normal(size=(B, K, S, D)).astype(np.float32)) * .3
         v = jnp.asarray(rng.normal(size=(B, K, S, D)).astype(np.float32))
-        out = fa_ops.mha(q, k, v, causal=True, block_q=32, block_k=32,
-                         interpret=True)
+        out = fa_ops.mha(q, k, v, causal=True, block_q=32, block_k=32)
         refv = fa_ref.mha(q, k, v, causal=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(refv),
                                    rtol=2e-4, atol=2e-4)
@@ -70,8 +69,7 @@ class TestFlashAttention:
         q = jnp.asarray(rng.normal(size=(B, H, 1, D)).astype(np.float32))
         k = jnp.asarray(rng.normal(size=(B, K, S, D)).astype(np.float32))
         v = jnp.asarray(rng.normal(size=(B, K, S, D)).astype(np.float32))
-        out = fa_ops.mha(q, k, v, causal=True, block_q=32, block_k=32,
-                         interpret=True)
+        out = fa_ops.mha(q, k, v, causal=True, block_q=32, block_k=32)
         refv = fa_ref.mha(q, k, v, causal=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(refv),
                                    rtol=2e-4, atol=2e-4)
@@ -81,8 +79,7 @@ class TestFlashAttention:
         q = jnp.asarray(rng.normal(size=(B, H, S, D)), jnp.bfloat16)
         k = jnp.asarray(rng.normal(size=(B, K, S, D)), jnp.bfloat16)
         v = jnp.asarray(rng.normal(size=(B, K, S, D)), jnp.bfloat16)
-        out = fa_ops.mha(q, k, v, causal=True, block_q=32, block_k=32,
-                         interpret=True)
+        out = fa_ops.mha(q, k, v, causal=True, block_q=32, block_k=32)
         refv = fa_ref.mha(q, k, v, causal=True)
         np.testing.assert_allclose(np.asarray(out, np.float32),
                                    np.asarray(refv, np.float32),
@@ -112,15 +109,13 @@ class TestFlashAttention:
         q = jnp.asarray(rng.normal(size=(B, H, sq, D)).astype(np.float32))
         k = jnp.asarray(rng.normal(size=(B, K, sk, D)).astype(np.float32))
         v = jnp.asarray(rng.normal(size=(B, K, sk, D)).astype(np.float32))
-        out1 = fa_ops.mha(q, k, v, causal=True, block_q=16, block_k=16,
-                          interpret=True)
+        out1 = fa_ops.mha(q, k, v, causal=True, block_q=16, block_k=16)
         # perturb the last key/value (future of every query when extra>0)
         if extra > 0:
             k2 = k.at[:, :, -1].add(10.0)
             v2 = v.at[:, :, -1].add(10.0)
             out2 = fa_ops.mha(q[:, :, :-1] if False else q, k2, v2,
-                              causal=True, block_q=16, block_k=16,
-                              interpret=True)
+                              causal=True, block_q=16, block_k=16)
             np.testing.assert_allclose(np.asarray(out1[:, :, :sq - 1]),
                                        np.asarray(out2[:, :, :sq - 1]),
                                        rtol=1e-5, atol=1e-5)
@@ -156,8 +151,7 @@ class TestSSD:
         B = jnp.asarray(rng.normal(size=(BH, S, N)).astype(np.float32)) * .3
         C = jnp.asarray(rng.normal(size=(BH, S, N)).astype(np.float32)) * .3
         y0, _ = ssd_ref.ssd_naive(x, dt, A, B, C)
-        y1 = ssd_scan(x, dt, dt * A[:, None], B, C, chunk=chunk,
-                      interpret=True)
+        y1 = ssd_scan(x, dt, dt * A[:, None], B, C, chunk=chunk)
         np.testing.assert_allclose(np.asarray(y0), np.asarray(y1),
                                    rtol=1e-4, atol=1e-5)
 
@@ -191,7 +185,7 @@ class TestMandelbrot:
         # the set boundary where an FMA-contraction ULP flips escape counts
         out = mb_ops.mandelbrot(H, W, x0=-2.0, y0=-1.0123,
                                 pixel_delta=2.0 / W,
-                                max_iterations=64, interpret=True)
+                                max_iterations=64)
         refv = mb_ref.mandelbrot(H, W, x0=-2.0, y0=-1.0123,
                                  pixel_delta=2.0 / W, max_iterations=64)
         same = np.asarray(out) == np.asarray(refv)
@@ -199,8 +193,7 @@ class TestMandelbrot:
 
     def test_interior_hits_escape_value(self):
         out = mb_ops.mandelbrot(64, 64, x0=-1.0, y0=-0.5,
-                                pixel_delta=1.0 / 64, max_iterations=50,
-                                interpret=True)
+                                pixel_delta=1.0 / 64, max_iterations=50)
         assert int((np.asarray(out) == 50).sum()) > 0  # interior present
 
 
@@ -215,8 +208,7 @@ class TestMoEGmm:
         x = jnp.asarray(rng.normal(size=(T, D)).astype(np.float32))
         eo = jnp.asarray(rng.integers(0, E, T))
         w = jnp.asarray(rng.normal(size=(E, D, F)).astype(np.float32)) * .1
-        y = gmm_ops.moe_apply(x, eo, w, tile_m=tile, tile_f=16,
-                              interpret=True)
+        y = gmm_ops.moe_apply(x, eo, w, tile_m=tile, tile_f=16)
         refv = gmm_ref.gmm(x, eo, w)
         np.testing.assert_allclose(np.asarray(y), np.asarray(refv),
                                    rtol=1e-5, atol=1e-5)
@@ -227,7 +219,7 @@ class TestMoEGmm:
         x = jnp.asarray(rng.normal(size=(T, D)).astype(np.float32))
         eo = jnp.full((T,), 2)
         w = jnp.asarray(rng.normal(size=(E, D, F)).astype(np.float32))
-        y = gmm_ops.moe_apply(x, eo, w, tile_m=8, tile_f=16, interpret=True)
+        y = gmm_ops.moe_apply(x, eo, w, tile_m=8, tile_f=16)
         np.testing.assert_allclose(np.asarray(y),
                                    np.asarray(x @ w[2]), rtol=1e-5,
                                    atol=1e-5)

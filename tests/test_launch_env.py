@@ -1,11 +1,16 @@
 """Launcher environment hygiene (``repro.launch._common``): the tcmalloc
 preload is opt-in (``--tcmalloc``), announced on stderr, and never
-clobbers an LD_PRELOAD the user already set."""
+clobbers an LD_PRELOAD the user already set.  The persistent compile cache
+(``repro.launch.cache``) goes where ``JAX_COMPILATION_CACHE_DIR`` says, or
+else to one fixed, git-ignored directory of the checkout."""
 
 import argparse
 import os
+from pathlib import Path
 
-from repro.launch import _common
+import jax
+
+from repro.launch import _common, cache
 
 
 def _args(**kw):
@@ -42,3 +47,22 @@ def test_tcmalloc_never_clobbers_existing_preload(monkeypatch, tmp_path):
     monkeypatch.setenv("LD_PRELOAD", "/opt/mine.so")
     _common.apply_runtime_env(_args(tcmalloc=True))
     assert os.environ["LD_PRELOAD"] == "/opt/mine.so"
+
+
+def test_compile_cache_defaults_to_a_fixed_ignored_dir(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    root = Path(__file__).resolve().parents[1]
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        assert cache.enable_compile_cache() == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(root / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+
+
+def test_compile_cache_env_var_wins(monkeypatch, tmp_path):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before  # set nothing else

@@ -3,7 +3,7 @@ a ServeEngine over pluggable decode backends.
 
 Fast-lane coverage: SlotPlan admission bookkeeping, engine token streams
 bit-identical to sequential per-request generation, the ``max_new=0``
-regression, admission-interleaving properties (hypothesis shim), the
+regression, admission-interleaving properties (hypothesis), the
 clustered decode farm (inprocess) matching the local backend, epoch-bumped
 scale-out with the §6.1.1 re-proof, the kill-during-serving simulator, and
 the deprecated FarmScheduler shim's legacy contract."""
@@ -14,7 +14,7 @@ import warnings
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.dataflow import NetworkError
 from repro.core.stream import SlotPlan

@@ -1,15 +1,10 @@
 """Property tests of the transport layer: pack/unpack round-trips, the
 epoch protocol, and drain/requeue losslessness — across all four transports.
-
-Runs through `tests/_hypothesis_compat.py`: with hypothesis installed these
-are real property sweeps; offline (the CI fast lane) `@given` degrades to a
-deterministic boundary grid and the tests stay green.
 """
 
 import numpy as np
 import pytest
-
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.cluster.transport import (InProcess, JaxMesh, MultiProcessPipe,
                                      SharedMemoryRing, _PipeEndpoint,
