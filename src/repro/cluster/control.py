@@ -167,7 +167,9 @@ def _serve_host(sub, ex, plan, host, endpoint, work_q, result_q,
     and the controller decides what happens next.
     """
     while True:
-        msg = work_q.get()
+        # the time this host sits warm with no batch posted to it
+        with ex.rec.span("park", "host"):
+            msg = work_q.get()
         if isinstance(msg, str) and msg == _SHUTDOWN:
             break
         # "replay_snap" messages append the on-disk fold snapshot to resume

@@ -220,9 +220,6 @@ class PartitionExecutor(StreamExecutor):
                 nbytes = _payload_bytes(v)
                 sp.set(nbytes=nbytes)
             self.recv_bytes[key] = self.recv_bytes.get(key, 0) + nbytes
-            if self.rec.enabled:
-                self.rec.counter(f"recv_bytes:{key}",
-                                 self.recv_bytes[key], "transport")
             if isinstance(v, str):
                 if v == SKIP:
                     v = _SKIP
@@ -248,9 +245,6 @@ class PartitionExecutor(StreamExecutor):
                                nbytes=nbytes):
                 self.ep.send(chan, ci, payload)
             self.sent_bytes[key] = self.sent_bytes.get(key, 0) + nbytes
-            if self.rec.enabled:
-                self.rec.counter(f"sent_bytes:{key}",
-                                 self.sent_bytes[key], "transport")
 
     def _local_collects(self) -> list:
         return [p for p in self.net.collects() if not is_shim(p.name)]

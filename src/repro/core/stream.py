@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import copy
 import dataclasses
+import re
 from collections import deque
 from typing import Any, Optional
 
@@ -478,35 +479,41 @@ class StreamExecutor:
                 jax.sharding.NamedSharding(self.cn.mesh, spec),)})
             # the counter body executes only while jax TRACES (cache miss /
             # new shape): a warm deployment must never tick it again
+            label = self._stage_label(name)
             self._jits[key] = jax.jit(
-                self._counted(self._stage_fn(name), self._stage_label(name)),
+                self._counted(self._stage_fn(name), label, f"stage_{label}"),
                 donate_argnums=(0,) if donate else (), **kw)
         return self._jits[key]
 
-    def _counted(self, fn, label):
+    def _counted(self, fn, label, program):
         """Wrap ``fn`` so the counter ticks whenever jax TRACES it — cache
         misses AND shape-driven retraces both show up, so "0 new traces" is
-        a truthful definition of a warm executor."""
+        a truthful definition of a warm executor.  The wrapper is named
+        ``program`` (non-identifier characters as ``_``), so the device
+        trace shows the program as ``jit_<program>``."""
         def counted(*args, _fn=fn, _label=label):
             self.trace_counts[_label] = self.trace_counts.get(_label, 0) + 1
             # this body runs only while jax traces, so the span brackets
             # exactly the trace/compile work (builds AND shape retraces)
             with self.rec.span("jit_trace", "compile", stage=_label):
                 return _fn(*args)
+        counted.__name__ = counted.__qualname__ = re.sub(r"\W", "_", program)
         return counted
 
     def _carry_jit(self, name: str):
         if ("carry", name) not in self._jits:
             self._record_build(("carry", name))
             self._jits[("carry", name)] = jax.jit(self._counted(
-                self.cn.collect_carry_fn(name), f"carry:{name}"))
+                self.cn.collect_carry_fn(name), f"carry:{name}",
+                f"carry_{name}"))
         return self._jits[("carry", name)]
 
     def _combine_carry_jit(self, name: str):
         if ("comb", name) not in self._jits:
             self._record_build(("comb", name))
             self._jits[("comb", name)] = jax.jit(self._counted(
-                self.cn.combine_carry_fn(name), f"comb:{name}"))
+                self.cn.combine_carry_fn(name), f"comb:{name}",
+                f"comb_{name}"))
         return self._jits[("comb", name)]
 
     def new_traces(self) -> int:
@@ -730,23 +737,27 @@ class StreamExecutor:
     # -- retirement (the only synchronisation point) -------------------------
     def _retire(self, entry, host_accs) -> None:
         ci, chunk_items, lanes_used, host_streams, watermark = entry
-        with self.rec.span("retire", "stream", ci=ci):
+        rec = self.rec
+        with rec.span("retire", "stream", ci=ci):
             # Collect is the CSP sink: block on this chunk's folded
             # accumulators (snapshots — later chunks' folds keep streaming
-            # behind them)
-            for acc in watermark.values():
-                jax.block_until_ready(acc)
+            # behind them) and on its host-folded streams
+            with rec.span("ready", "stream", ci=ci):
+                for acc in watermark.values():
+                    jax.block_until_ready(acc)
+                host_streams = {name: jax.block_until_ready(stream)
+                                for name, stream in host_streams.items()}
             for name, stream in host_streams.items():
                 p = self.net.procs[name]
-                stream = jax.block_until_ready(stream)
-                self.rec.instant("collect", "csp", collect=name, ci=ci)
+                rec.instant("collect", "csp", collect=name, ci=ci)
                 leaves = jax.tree_util.tree_leaves(stream)
                 n = leaves[0].shape[0] if leaves else 0
-                acc = host_accs[name]
-                for i in range(n):
-                    item = jax.tree_util.tree_map(lambda a: a[i], stream)
-                    acc = p.fn(acc, item)
-                host_accs[name] = acc
+                with rec.span("fold", "stream", ci=ci, collect=name, items=n):
+                    acc = host_accs[name]
+                    for i in range(n):
+                        item = jax.tree_util.tree_map(lambda a: a[i], stream)
+                        acc = p.fn(acc, item)
+                    host_accs[name] = acc
         self.stats.chunks_done += 1
         self.stats.items_done += chunk_items
         for lane in lanes_used:

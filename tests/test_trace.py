@@ -326,6 +326,112 @@ class TestClusterTrace:
         assert c._cum_chan == {"a->b": [1000.0, 2.0]}
 
 
+def _host_farm_factory(workers):
+    """A farm whose Collect folds on the host, item by item."""
+    return DataParallelCollect(
+        create=lambda i: jnp.asarray(float(i)),
+        function=lambda x: x * x,
+        collector=lambda a, x: a + float(x), init=0.0,
+        workers=workers, jit_combine=False)
+
+
+def _inside(inner, outer) -> bool:
+    return (outer.ts <= inner.ts
+            and inner.ts + inner.dur <= outer.ts + outer.dur)
+
+
+class TestHostWaitSpans:
+    def test_every_worker_host_records_park(self):
+        net = _farm_factory(2)
+        with ClusterDeployment(net, hosts=2, transport="inprocess",
+                               microbatch_size=2, trace=True) as dep:
+            dep.run(instances=6)
+            dep.run(instances=6)
+            ev = dep.merged_trace()
+        parks = [e for e in ev if e.name == "park"]
+        assert {e.host for e in parks} == {0, 1}
+        # one park per batch posted to a host
+        assert all(sum(e.host == h for e in parks) == 2 for h in (0, 1))
+        assert all(e.kind == "span" and e.dur >= 0 for e in parks)
+
+    def test_ready_and_fold_nest_inside_retire(self):
+        net = _host_farm_factory(2)
+        with ClusterDeployment(net, hosts=2, transport="inprocess",
+                               microbatch_size=2, trace=True) as dep:
+            out = dep.run(instances=7)
+            ev = dep.merged_trace()
+            collect_host = dep.plan.assignment["collect"]
+        assert out["collect"] == sum(i * i for i in range(7))
+        retires = {e.args["ci"]: e for e in ev
+                   if e.name == "retire" and e.host == collect_host}
+        readies = [e for e in ev
+                   if e.name == "ready" and e.host == collect_host]
+        folds = [e for e in ev if e.name == "fold"]
+        assert {e.host for e in folds} == {collect_host}
+        assert sorted(e.args["ci"] for e in folds) == [0, 1, 2, 3]
+        assert sorted(e.args["ci"] for e in readies) == [0, 1, 2, 3]
+        for f in folds:
+            assert f.args["collect"] == "collect"
+            # microbatches of 2 over 7 items: the last chunk holds one
+            assert f.args["items"] == (1 if f.args["ci"] == 3 else 2)
+        for e in readies + folds:
+            assert _inside(e, retires[e.args["ci"]]), e
+        ready_of = {e.args["ci"]: e for e in readies}
+        for f in folds:
+            r = ready_of[f.args["ci"]]
+            assert r.ts + r.dur <= f.ts  # waiting, then folding
+        conf = trace.check_conformance(net, ev)
+        assert conf.ok and conf.coverage == 1.0, conf.detail
+
+    def test_byte_counter_events_are_gone(self):
+        net = _farm_factory(2)
+        with ClusterDeployment(net, hosts=2, transport="inprocess",
+                               microbatch_size=2, trace=True) as dep:
+            dep.run(instances=8)
+            ev = dep.merged_trace()
+            rates = dep.metrics().bytes_per_s
+        assert not [e for e in ev if e.name.startswith(
+            ("sent_bytes:", "recv_bytes:"))]
+        recvs = [e for e in ev if e.name == "recv"]
+        assert recvs and all(e.args["nbytes"] > 0 for e in recvs)
+        assert rates and all(v > 0 for v in rates.values())
+
+
+def _two_stage_net():
+    from repro.core import Collect, Emit, Network
+    from repro.core.processes import Worker
+
+    net = Network("ab")
+    net.add(Emit(lambda i: jnp.asarray(float(i)), name="emit"),
+            Worker(lambda x: x * x, name="a"),
+            Worker(lambda x: x + 1.0, name="b"),
+            Collect(lambda a, x: a + x, init=jnp.asarray(0.0),
+                    jit_combine=True, name="collect"))
+    return net
+
+
+class TestProgramNames:
+    @pytest.mark.parametrize("fuse, head, module", [
+        (False, "a", "jit_stage_a"),
+        (True, "a", "jit_stage_a_b"),
+    ])
+    def test_stage_jit_is_named_after_its_stage(self, fuse, head, module):
+        from repro.core.stream import StreamExecutor
+
+        ex = StreamExecutor(build(_two_stage_net()), microbatch_size=2,
+                            fuse=fuse)
+        text = ex._stage_jit(head, False).lower(jnp.zeros(2)).as_text()
+        assert f"module @{module} " in text
+
+    def test_carry_jit_is_named_after_its_collect(self):
+        from repro.core.stream import StreamExecutor
+
+        ex = StreamExecutor(build(_two_stage_net()), microbatch_size=2)
+        text = ex._carry_jit("collect").lower(
+            jnp.asarray(0.0), jnp.zeros(2)).as_text()
+        assert "module @jit_carry_collect " in text
+
+
 class TestSimGoldenTrace:
     def _one(self):
         """One no-fault sim deployment under per-host counting clocks."""
