@@ -28,6 +28,7 @@ from typing import Any, Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .dataflow import Distribution, Kind, Network, NetworkError, ProcessDef
 from .verify import verify
@@ -372,14 +373,8 @@ class CompiledNetwork:
             if p.jit_combine:
                 val = results[name]
             else:
-                stream = host_streams[name]
-                leaves = jax.tree_util.tree_leaves(stream)
-                n = leaves[0].shape[0] if leaves else 0
-                acc = copy.deepcopy(p.init)
-                for i in range(n):
-                    item = jax.tree_util.tree_map(lambda a: a[i], stream)
-                    acc = p.fn(acc, item)
-                val = acc
+                val = fold_host_stream(p.fn, copy.deepcopy(p.init),
+                                       host_stream(host_streams[name]))
             out[name] = p.finalise(val) if p.finalise else val
         return out
 
@@ -516,6 +511,30 @@ def _fan_merge(xs):
         return stacked.reshape((-1,) + stacked.shape[2:])
 
     return jax.tree_util.tree_map(_merge, *xs)
+
+
+def start_host_copy(tree) -> None:
+    """Start copying every device leaf of ``tree`` to the host, without
+    waiting: a later ``np.asarray`` of the leaf takes the copy as it is."""
+    for leaf in jax.tree_util.tree_leaves(tree):
+        if isinstance(leaf, jax.Array):
+            leaf.copy_to_host_async()
+
+
+def host_stream(stream):
+    """A batched stream brought to the host in one copy per leaf (numpy)."""
+    start_host_copy(stream)
+    return jax.tree_util.tree_map(np.asarray, stream)
+
+
+def fold_host_stream(fn, acc, stream):
+    """A host Collect's fold (``jit_combine=False``): ``fn`` over the items
+    of a :func:`host_stream` in item order.  Items are numpy views or
+    scalars sliced on the host, so the loop launches no device program."""
+    leaves, treedef = jax.tree_util.tree_flatten(stream)
+    for i in range(leaves[0].shape[0] if leaves else 0):
+        acc = fn(acc, treedef.unflatten([leaf[i] for leaf in leaves]))
+    return acc
 
 
 def _fold_batch(combine: Callable, x, init=None):

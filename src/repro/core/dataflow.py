@@ -98,7 +98,12 @@ class ProcessDef:
     * EMIT:    ``fn(index:int) -> item``  (host) or a ``DataSource`` object
     * WORKER:  ``fn(item, *modifier) -> item``  (pure, jax-traceable unless
                ``host_only=True``)
-    * COLLECT: ``fn(acc, item) -> acc``  with ``init`` and ``finalise(acc)``
+    * COLLECT: ``fn(acc, item) -> acc``  with ``init`` and ``finalise(acc)``.
+               A host fold (``jit_combine=False``) is handed host items:
+               numpy arrays or scalars, sliced from one host copy of each
+               chunk (the sequential oracle hands what the worker returned).
+               A fold that wants device arithmetic declares
+               ``jit_combine=True``.
     * SPREADER/REDUCER: ``fn`` unused (``COMBINE`` uses ``fn(a, b) -> a``)
     """
 

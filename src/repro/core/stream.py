@@ -51,7 +51,8 @@ from typing import Any, Optional
 import jax
 
 from . import trace as _trace
-from .builder import CompiledNetwork, _fan_merge, _fan_split
+from .builder import (CompiledNetwork, _fan_merge, _fan_split,
+                      fold_host_stream, host_stream, start_host_copy)
 from .dataflow import Distribution, Kind, Network, NetworkError
 from .processes import (AnyFanOne, Collect, Emit, OneFanAny, Worker)
 
@@ -741,23 +742,25 @@ class StreamExecutor:
         with rec.span("retire", "stream", ci=ci):
             # Collect is the CSP sink: block on this chunk's folded
             # accumulators (snapshots — later chunks' folds keep streaming
-            # behind them) and on its host-folded streams
+            # behind them) and on its host-folded streams, each brought to
+            # the host in one copy (started at dispatch)
             with rec.span("ready", "stream", ci=ci):
                 for acc in watermark.values():
                     jax.block_until_ready(acc)
-                host_streams = {name: jax.block_until_ready(stream)
-                                for name, stream in host_streams.items()}
+                for name, stream in host_streams.items():
+                    jax.block_until_ready(stream)
+                    leaves = jax.tree_util.tree_leaves(stream)
+                    with rec.span("to_host", "stream", ci=ci, collect=name,
+                                  nbytes=sum(l.nbytes for l in leaves)):
+                        host_streams[name] = host_stream(stream)
             for name, stream in host_streams.items():
                 p = self.net.procs[name]
                 rec.instant("collect", "csp", collect=name, ci=ci)
                 leaves = jax.tree_util.tree_leaves(stream)
                 n = leaves[0].shape[0] if leaves else 0
                 with rec.span("fold", "stream", ci=ci, collect=name, items=n):
-                    acc = host_accs[name]
-                    for i in range(n):
-                        item = jax.tree_util.tree_map(lambda a: a[i], stream)
-                        acc = p.fn(acc, item)
-                    host_accs[name] = acc
+                    host_accs[name] = fold_host_stream(p.fn, host_accs[name],
+                                                       stream)
         self.stats.chunks_done += 1
         self.stats.items_done += chunk_items
         for lane in lanes_used:
@@ -903,6 +906,9 @@ class StreamExecutor:
                 streams, host_streams, lanes_used = self._dispatch_chunk(
                     ci, chunk, final=ci == len(plan) - 1)
                 self._forward_egress(ci, host_streams)
+                # what egress left is folded here, on the host: start its
+                # copy now, so it overlaps the chunks dispatched after it
+                start_host_copy(host_streams)
                 for name, x in streams.items():
                     rec.instant("collect", "csp", collect=name, ci=ci)
                     if name not in jit_accs:  # first chunk: fold with init

@@ -7,6 +7,12 @@ deployment: the partitioned network trace-refines the unpartitioned one
 oracle bit-for-bit.
 """
 
+import json
+import os
+import subprocess
+import sys
+import textwrap
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -25,6 +31,9 @@ from repro.core import (Collect, CombineNto1, DataParallelCollect, Emit,
                         OnePipelineCollect, OneSeqCastList, Worker, build,
                         csp, netlog, run_sequential)
 from repro.core.dataflow import Kind
+
+_ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+_SRC = os.path.join(_ROOT, "src")
 
 
 def _sq(x):
@@ -1033,6 +1042,79 @@ class TestJaxMesh:
     def test_unknown_transport_rejected(self):
         with pytest.raises(NetworkError, match="unknown transport"):
             make_transport("carrier-pigeon")
+
+    def test_mandelbrot_stage_per_device_host_fold(self):
+        """The Mandelbrot farm with one stage per device (4 CPU devices, as
+        the benchmark lays it over four chips): bit-identical to the fused
+        run, every band folded once, and only the collect host copies its
+        chunks to the host — the stage hosts' egress stays on the device."""
+        code = textwrap.dedent("""
+            import json, os, sys
+            os.environ["XLA_FLAGS"] = \
+                "--xla_force_host_platform_device_count=4"
+            sys.path[:0] = [%r, %r]
+            import dataclasses
+            import jax
+            import numpy as np
+            from examples.mandelbrot import _assemble, make_net
+            from repro.cluster import ClusterDeployment, partition
+            from repro.cluster.transport import JaxMesh
+            from repro.core import build
+
+            bands = 8
+            net = make_net(32, 16, bands, 20)
+            one = _assemble(build(net).run(instances=bands)["collect"])
+            plan = partition(net, assignment={"emit": 0, "ofa": 1,
+                                              "group": 2, "afo": 3,
+                                              "collect": 3})
+            sent = {}
+
+            class Probe(JaxMesh):
+                def _send_transform(self, chan, value):
+                    sent.setdefault(plan.assignment[chan[0]], set()).update(
+                        "device" if isinstance(l, jax.Array) else "host"
+                        for l in jax.tree_util.tree_leaves(value))
+                    return super()._send_transform(chan, value)
+
+            delivered = []
+            fold = net.procs["collect"].fn
+
+            def counted(acc, item):
+                delivered.append(int(item[0]))
+                return fold(acc, item)
+
+            net.procs["collect"] = dataclasses.replace(
+                net.procs["collect"], fn=counted)
+            same = []
+            with ClusterDeployment(net, plan=plan,
+                                   transport=Probe(devices=jax.devices()[:4]),
+                                   microbatch_size=2, trace=True) as dep:
+                for _ in range(2):
+                    out = dep.run(instances=bands)["collect"]
+                    same.append(bool(np.array_equal(_assemble(out), one)))
+                ev = dep.merged_trace()
+            copies = [(e.host, e.args["ci"], e.args["nbytes"])
+                      for e in ev if e.name == "to_host"]
+            print(json.dumps({"same": same, "delivered": delivered,
+                              "copies": copies, "n_hosts": plan.n_hosts,
+                              "sent": {h: sorted(m)
+                                       for h, m in sent.items()}}))
+        """ % (_SRC, _ROOT))
+        r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                           text=True, timeout=300)
+        assert r.returncode == 0, r.stderr[-3000:]
+        got = json.loads(r.stdout.strip().splitlines()[-1])
+        assert got["n_hosts"] == 4
+        assert got["same"] == [True, True]
+        rows = [b * 2 for b in range(8)]  # band height 16 // 8
+        assert got["delivered"] == rows + rows  # each band once, in order
+        # four chunks of two bands a batch, each one copy of (2,) int32
+        # top rows and (2, 2, 32) int32 counts, all on the collect host
+        chunk_bytes = 2 * 4 + 2 * 2 * 32 * 4
+        assert sorted(map(tuple, got["copies"])) == sorted(
+            [(3, ci, chunk_bytes) for ci in range(4)] * 2)
+        assert got["sent"] == {"0": ["device"], "1": ["device"],
+                               "2": ["device"]}
 
 
 class TestCostPartitioning:

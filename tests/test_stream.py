@@ -263,6 +263,101 @@ class TestStreamingEquivalence:
         assert len(cn._streams) == 1  # same executor (and stage jits) reused
 
 
+def _band_net(seen: list, band_h: int = 3, width: int = 5):
+    """A farm shaped like the Mandelbrot example: each item is a band's top
+    row and its int32 counts; the host fold keeps bands in a dict."""
+    def create(i):
+        return jnp.asarray(i * band_h, jnp.int32)
+
+    def render(row0):
+        rows = row0 + jnp.arange(band_h, dtype=jnp.int32)[:, None]
+        return (row0, rows * 7 + jnp.arange(width, dtype=jnp.int32))
+
+    def collector(acc, item):
+        seen.append(item)
+        row0, cnt = item
+        acc[int(row0)] = np.asarray(cnt)
+        return acc
+
+    return DataParallelCollect(create=create, function=render,
+                               collector=collector, init={}, workers=2)
+
+
+def _float_net(seen: list):
+    def collector(acc, x):
+        seen.append(x)
+        return {**acc, len(acc): float(x)}
+
+    return DataParallelCollect(create=_mk_items(7), function=_sq,
+                               collector=collector, init={}, workers=2)
+
+
+def _run_mode(net, mode: str, n: int):
+    if mode == "sequential":
+        return run_sequential(net, n)["collect"]
+    cn = build(net)
+    if mode == "streaming":  # microbatches of 2: the last chunk holds one
+        return cn.run_streaming(instances=n, microbatch_size=2)["collect"]
+    return cn.run(instances=n, logged=mode == "logged")["collect"]
+
+
+def _device_items(net, n: int) -> list:
+    """The items as per-item slices of the device stream."""
+    cn = build(net)
+    stream = jax.vmap(net.procs["group"].fn)(cn.make_batch(n))
+    return [jax.tree_util.tree_map(lambda a: np.asarray(a[i]), stream)
+            for i in range(n)]
+
+
+class TestHostFoldItems:
+    """A host Collect (``jit_combine=False``) is handed host (numpy) items:
+    each chunk comes to the host in one copy, and the fold slices it there."""
+
+    @pytest.mark.parametrize("make", [_band_net, _float_net],
+                             ids=["bands", "floats"])
+    @pytest.mark.parametrize("mode", ["run", "logged", "streaming"])
+    def test_items_are_numpy_equal_to_device_slices(self, make, mode):
+        seen: list = []
+        _run_mode(make(seen), mode, 7)
+        want = _device_items(make([]), 7)
+        assert len(seen) == 7
+        for got, ref in zip(seen, want):
+            got_leaves = jax.tree_util.tree_leaves(got)
+            ref_leaves = jax.tree_util.tree_leaves(ref)
+            assert len(got_leaves) == len(ref_leaves)
+            for g, r in zip(got_leaves, ref_leaves):
+                assert isinstance(g, (np.ndarray, np.generic)), type(g)
+                assert not isinstance(g, jax.Array)
+                assert g.dtype == r.dtype and np.shape(g) == r.shape
+                np.testing.assert_array_equal(g, r)
+
+    @pytest.mark.parametrize("make", [_band_net, _float_net],
+                             ids=["bands", "floats"])
+    def test_results_bit_identical_across_modes(self, make):
+        outs = {mode: _run_mode(make([]), mode, 7)
+                for mode in ("sequential", "run", "logged", "streaming")}
+        seq = outs.pop("sequential")
+        assert len(seq) == 7
+        for mode, out in outs.items():
+            assert out.keys() == seq.keys(), mode
+            for k in seq:
+                a, b = np.asarray(seq[k]), np.asarray(out[k])
+                assert a.dtype == b.dtype, mode
+                np.testing.assert_array_equal(a, b)
+
+    def test_device_fold_still_sees_device_values(self):
+        """``jit_combine=True`` folds stay on the device: no host copy."""
+        net = DataParallelCollect(create=_mk_items(7), function=_sq,
+                                  collector=_add, init=jnp.asarray(0.0),
+                                  workers=2, jit_combine=True)
+        cn = build(net)
+        for out in (cn.run(instances=7)["collect"],
+                    cn.run_streaming(instances=7,
+                                     microbatch_size=2)["collect"]):
+            assert isinstance(out, jax.Array)
+            assert float(out) == float(sum(i * i for i in range(7)))
+
+
 class TestChunkReplay:
     """Chunk-replay bookkeeping (the cluster control plane's foundation):
     an interrupted run captures a resumable state iff the interruption hit

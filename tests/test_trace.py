@@ -383,6 +383,35 @@ class TestHostWaitSpans:
         conf = trace.check_conformance(net, ev)
         assert conf.ok and conf.coverage == 1.0, conf.detail
 
+    def test_one_to_host_copy_per_chunk_inside_ready(self):
+        net = _host_farm_factory(2)
+        with ClusterDeployment(net, hosts=2, transport="inprocess",
+                               microbatch_size=2, trace=True) as dep:
+            out = dep.run(instances=7)
+            ev = dep.merged_trace()
+            collect_host = dep.plan.assignment["collect"]
+        assert out["collect"] == sum(i * i for i in range(7))
+        copies = [e for e in ev if e.name == "to_host"]
+        assert {e.host for e in copies} == {collect_host}
+        # exactly one per chunk of the one host fold
+        assert sorted(e.args["ci"] for e in copies) == [0, 1, 2, 3]
+        ready_of = {e.args["ci"]: e for e in ev
+                    if e.name == "ready" and e.host == collect_host}
+        for e in copies:
+            assert e.kind == "span" and e.args["collect"] == "collect"
+            assert _inside(e, ready_of[e.args["ci"]]), e
+            # float32 items, microbatches of 2 over 7: the last holds one
+            assert e.args["nbytes"] == 4 * (1 if e.args["ci"] == 3 else 2)
+
+    def test_device_fold_records_no_to_host(self):
+        net = _farm_factory(2)
+        with ClusterDeployment(net, hosts=2, transport="inprocess",
+                               microbatch_size=2, trace=True) as dep:
+            dep.run(instances=7)
+            ev = dep.merged_trace()
+        assert [e for e in ev if e.name == "ready"]
+        assert not [e for e in ev if e.name == "to_host"]
+
     def test_byte_counter_events_are_gone(self):
         net = _farm_factory(2)
         with ClusterDeployment(net, hosts=2, transport="inprocess",
