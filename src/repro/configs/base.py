@@ -23,6 +23,38 @@ class MoEConfig:
     layer0_dense: bool = False  # deepseek: first layer is a dense FFN
     capacity_factor: float = 1.25
     router_norm_topk: bool = False  # normalise top-k weights to sum 1
+    # experts this chip holds (expert parallelism): the router scores all
+    # n_experts, the layer computes the part of experts 0..n_held-1,
+    # dropless, through the grouped path; 0 = all
+    n_held: int = 0
+    # DeepSeek-V2's expert-level balance loss per sequence (arXiv:2405.04434)
+    # in place of the switch-style loss on the top-1 choice
+    seq_aux: bool = False
+    aux_weight: float = 0.01  # the balance loss's weight in the total loss
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """DeepSeek-V2 multi-head latent attention without query compression:
+    keys and values come from one ``kv_lora_rank`` latent per position, and
+    one rope key of ``qk_rope_head_dim`` is shared by all heads."""
+
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling: the published ``rope_scaling`` keys."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,6 +108,8 @@ class ModelConfig:
     ssm: Optional[SSMConfig] = None
     hybrid: Optional[HybridConfig] = None
     encdec: Optional[EncDecConfig] = None
+    mla: Optional[MLAConfig] = None
+    yarn: Optional[YarnConfig] = None
     mrope: bool = False
     mrope_sections: Tuple[int, int, int] = (16, 24, 24)
     # numerics / execution
@@ -132,7 +166,12 @@ class ModelConfig:
                 layer0_dense=self.moe.layer0_dense,
                 # dropless at smoke scale so forward ≡ prefill+decode
                 capacity_factor=4.0,
-                router_norm_topk=self.moe.router_norm_topk)
+                router_norm_topk=self.moe.router_norm_topk,
+                n_held=2 if self.moe.n_held else 0,
+                seq_aux=self.moe.seq_aux, aux_weight=self.moe.aux_weight)
+        if self.mla:
+            kw["mla"] = MLAConfig(kv_lora_rank=32, qk_nope_head_dim=16,
+                                  qk_rope_head_dim=8, v_head_dim=16)
         if self.ssm:
             kw["ssm"] = SSMConfig(d_state=16, head_dim=16,
                                   expand=2, conv_kernel=4,
@@ -145,10 +184,11 @@ class ModelConfig:
             kw["encdec"] = EncDecConfig(n_enc_layers=2, frontend_downsample=4)
         if self.mrope:
             kw["mrope_sections"] = (4, 6, 6)
-        for k in ("moe", "ssm", "hybrid", "encdec"):
+        for k in ("moe", "ssm", "hybrid", "encdec", "mla", "yarn"):
             if isinstance(kw[k], dict):
                 cls = {"moe": MoEConfig, "ssm": SSMConfig,
-                       "hybrid": HybridConfig, "encdec": EncDecConfig}[k]
+                       "hybrid": HybridConfig, "encdec": EncDecConfig,
+                       "mla": MLAConfig, "yarn": YarnConfig}[k]
                 kw[k] = cls(**kw[k])
         return ModelConfig(**kw)
 

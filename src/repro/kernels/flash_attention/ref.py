@@ -6,11 +6,22 @@ import jax
 import jax.numpy as jnp
 
 
+def _softmax(logits: jax.Array) -> jax.Array:
+    """Softmax over the last axis as ``exp(x - logsumexp(x))``.  Written
+    through ``logsumexp`` because the TPU compiler turns the usual
+    max-subtract-normalise form of a long masked row into a sliding-window
+    max over the whole row (a ``reduce-window`` of twice the row's length),
+    some forty times slower than the dot that feeds it."""
+    return jnp.exp(logits - jax.nn.logsumexp(logits, axis=-1, keepdims=True))
+
+
 def mha(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
         scale: float | None = None) -> jax.Array:
-    """q: (B, H, Sq, D); k, v: (B, K, Sk, D) with K | H.  Returns (B, H, Sq, D).
+    """q: (B, H, Sq, D); k: (B, K, Sk, D); v: (B, K, Sk, Dv) with K | H.
+    Returns (B, H, Sq, Dv).
 
     Grouped-query attention: query head h attends with kv head h // (H // K).
+    The value head dim may differ from the query/key one (latent attention).
     """
     B, H, Sq, D = q.shape
     K = k.shape[1]
@@ -28,10 +39,10 @@ def mha(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
         qi = jnp.arange(Sq)[:, None] + (Sk - Sq)
         ki = jnp.arange(Sk)[None, :]
         logits = jnp.where((ki <= qi)[None, None, None], logits, -jnp.inf)
-    probs = jax.nn.softmax(logits, axis=-1)
+    probs = _softmax(logits)
     out = jnp.einsum("bkgql,bkld->bkgqd", probs.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-    return out.reshape(B, H, Sq, D).astype(q.dtype)
+    return out.reshape(B, H, Sq, v.shape[-1]).astype(q.dtype)
 
 
 def mha_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -44,7 +55,9 @@ def mha_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
     kernel is the TPU-native version; this one also fixes the dry-run's
     memory picture since Mosaic kernels are opaque to the CPU backend).
     Softmax per q-chunk runs over the full key axis, so no online-softmax
-    carry is needed — exactness is structural.
+    carry is needed — exactness is structural.  The chunk body is
+    rematerialised: under autodiff the backward recomputes one chunk's
+    probabilities at a time instead of keeping every chunk's.
     """
     B, H, Sq, D = q.shape
     K, Sk = k.shape[1], k.shape[2]
@@ -66,12 +79,13 @@ def mha_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
             qi = blk * chunk + jnp.arange(chunk)[:, None] + diag
             logits = jnp.where((ki <= qi)[None, None, None], logits,
                                -jnp.inf)
-        probs = jax.nn.softmax(logits, axis=-1)
+        probs = _softmax(logits)
         out = jnp.einsum("bkgql,bkld->bkgqd", probs.astype(v.dtype), v,
                          preferred_element_type=jnp.float32)
         return carry, out.astype(q.dtype)
 
+    body = jax.checkpoint(body, prevent_cse=False)
     _, blocks = jax.lax.scan(body, None,
                              (qb, jnp.arange(nc, dtype=jnp.int32)))
-    out = jnp.moveaxis(blocks, 0, 3)  # (B,K,G,nc,chunk,D)
-    return out.reshape(B, H, Sq, D)
+    out = jnp.moveaxis(blocks, 0, 3)  # (B,K,G,nc,chunk,Dv)
+    return out.reshape(B, H, Sq, v.shape[-1])

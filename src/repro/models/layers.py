@@ -66,12 +66,18 @@ def norm(kind: str):
 # --------------------------------------------------------------------------
 
 def rope_angles(positions: jax.Array, rot_dim: int, theta: float,
-                sections: Optional[tuple] = None) -> tuple:
+                sections: Optional[tuple] = None, yarn=None) -> tuple:
     """positions: (B, S) int — or (B, S, 3) for M-RoPE with ``sections``
     (t, h, w) summing to rot_dim // 2.  Returns cos, sin: (B, S, rot_dim/2).
+    ``yarn`` (a :class:`~repro.configs.YarnConfig`) scales the frequencies
+    and cos/sin as YaRN does (:func:`yarn_inv_freq`).
     """
     half = rot_dim // 2
-    inv = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if yarn is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32)
+                               / half))
+    else:
+        inv = yarn_inv_freq(rot_dim, theta, yarn)
     if sections is None:
         ang = positions.astype(jnp.float32)[..., None] * inv  # (B,S,half)
     else:
@@ -84,7 +90,37 @@ def rope_angles(positions: jax.Array, rot_dim: int, theta: float,
                              positions.shape[:2] + (half,)).astype(jnp.int32),
             axis=-1)  # (B,S,half): per-freq position stream
         ang = pos * inv
-    return jnp.cos(ang), jnp.sin(ang)
+    if yarn is None:
+        return jnp.cos(ang), jnp.sin(ang)
+    m = (yarn_mscale(yarn.factor, yarn.mscale)
+         / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+    return jnp.cos(ang) * m, jnp.sin(ang) * m
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature factor, ``0.1 mscale ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, yarn) -> jax.Array:
+    """YaRN's rotary frequencies, (dim/2,): each frequency blends the plain
+    one (``theta^(-2i/dim)``) and it divided by ``factor``; the blend ramps
+    linearly between the correction dims ``lo`` and ``hi`` found from
+    ``beta_fast`` and ``beta_slow`` rotations over the original context."""
+    def corr(rotations):
+        return (dim * math.log(yarn.original_max_position_embeddings
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(corr(yarn.beta_fast)), 0)
+    hi = min(math.ceil(corr(yarn.beta_slow)), dim - 1)
+    if lo == hi:
+        hi += 0.001
+    extra = 1.0 / theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - lo)
+                    / (hi - lo), 0.0, 1.0)
+    mask = 1.0 - ramp
+    return extra / yarn.factor * (1.0 - mask) + extra * mask
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
@@ -123,20 +159,22 @@ def attention_init(key, cfg, dtype) -> dict:
 
 
 def _sdpa(q, k, v, *, causal: bool, use_pallas: bool,
-          attn_chunk: int = 0) -> jax.Array:
-    """q: (B,S,H,hd); k,v: (B,T,K,hd) → (B,S,H,hd).  BHSD under the hood."""
+          attn_chunk: int = 0, scale: Optional[float] = None) -> jax.Array:
+    """q, k: (B,S|T,H|K,hd); v: (B,T,K,hd_v) → (B,S,H,hd_v).  BHSD under
+    the hood."""
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
     if use_pallas:
         from repro.kernels.flash_attention import ops as fops
-        ot = fops.mha(qt, kt, vt, causal=causal)
+        ot = fops.mha(qt, kt, vt, causal=causal, scale=scale)
     elif attn_chunk:
         from repro.kernels.flash_attention import ref as fref
-        ot = fref.mha_chunked(qt, kt, vt, causal=causal, chunk=attn_chunk)
+        ot = fref.mha_chunked(qt, kt, vt, causal=causal, scale=scale,
+                              chunk=attn_chunk)
     else:
         from repro.kernels.flash_attention import ref as fref
-        ot = fref.mha(qt, kt, vt, causal=causal)
+        ot = fref.mha(qt, kt, vt, causal=causal, scale=scale)
     return jnp.swapaxes(ot, 1, 2)
 
 
@@ -178,42 +216,25 @@ def attention(p: dict, cfg, x: jax.Array, *, positions: jax.Array,
         idx = cache["index"]  # (B,) per-row write position
         if advance is None:
             advance = jnp.ones((B,), bool)
-        upd = jax.vmap(
-            lambda c, n, i: jax.lax.dynamic_update_slice_in_dim(
-                c, n, i, axis=0))
         if cfg.kv_quant:
             kq, ks = _kv_quantize(k)
             vq, vs = _kv_quantize(v)
-            ck_q = upd(cache["k"], kq, idx)
-            cv_q = upd(cache["v"], vq, idx)
-            cks = upd(cache["k_scale"], ks, idx)
-            cvs = upd(cache["v_scale"], vs, idx)
+            ck_q = _cache_write(cache["k"], kq, idx)
+            cv_q = _cache_write(cache["v"], vq, idx)
+            cks = _cache_write(cache["k_scale"], ks, idx)
+            cvs = _cache_write(cache["v_scale"], vs, idx)
             new_idx = idx + jnp.where(advance, S, 0).astype(idx.dtype)
             new_cache = {"k": ck_q, "v": cv_q, "k_scale": cks,
                          "v_scale": cvs, "index": new_idx}
             k = _kv_dequantize(ck_q, cks, x.dtype)
             v = _kv_dequantize(cv_q, cvs, x.dtype)
         else:
-            ck = upd(cache["k"], k, idx)
-            cv = upd(cache["v"], v, idx)
+            ck = _cache_write(cache["k"], k, idx)
+            cv = _cache_write(cache["v"], v, idx)
             new_idx = idx + jnp.where(advance, S, 0).astype(idx.dtype)
             new_cache = {"k": ck, "v": cv, "index": new_idx}
             k, v = ck, cv
-        # per-row causality: row b's queries sit at positions idx_b + [0,S).
-        # GQA via grouped einsum — never materialise repeated (or f32) KV.
-        T = k.shape[1]
-        group = H // K
-        qg = q.reshape(B, S, K, group, hd)
-        logits = jnp.einsum("bskgd,btkd->bkgst", qg, k,
-                            preferred_element_type=jnp.float32) * (hd ** -0.5)
-        ki = jnp.arange(T)[None, None, None, None, :]
-        qi = (idx[:, None, None, None, None]
-              + jnp.arange(S)[None, None, None, :, None])
-        logits = jnp.where(ki <= qi, logits, -jnp.inf)
-        probs = jax.nn.softmax(logits, axis=-1)
-        ot = jnp.einsum("bkgst,btkd->bskgd", probs.astype(v.dtype), v,
-                        preferred_element_type=jnp.float32)
-        out = ot.reshape(B, S, H, hd).astype(x.dtype)
+        out = _attend_cached(q, k, v, idx, hd ** -0.5).astype(x.dtype)
     else:
         out = _sdpa(q, k, v, causal=causal, use_pallas=cfg.use_pallas,
                     attn_chunk=cfg.attn_chunk)
@@ -222,7 +243,129 @@ def attention(p: dict, cfg, x: jax.Array, *, positions: jax.Array,
     return act(out, "batch", "seq", "d"), new_cache
 
 
+def _attend_cached(q, k, v, idx, scale: float) -> jax.Array:
+    """Attention over a cache: q (B,S,H,d), k (B,T,K,d), v (B,T,K,d_v);
+    row b's queries sit at positions ``idx[b] + [0, S)`` and see the cache
+    up to themselves.  GQA via grouped einsum — never materialise repeated
+    (or f32) KV.  Returns (B,S,H,d_v) in float32."""
+    B, S, H, d = q.shape
+    T, K = k.shape[1], k.shape[2]
+    group = H // K
+    qg = q.reshape(B, S, K, group, d)
+    logits = jnp.einsum("bskgd,btkd->bkgst", qg, k,
+                        preferred_element_type=jnp.float32) * scale
+    ki = jnp.arange(T)[None, None, None, None, :]
+    qi = (idx[:, None, None, None, None]
+          + jnp.arange(S)[None, None, None, :, None])
+    logits = jnp.where(ki <= qi, logits, -jnp.inf)
+    probs = jax.nn.softmax(logits, axis=-1)
+    ot = jnp.einsum("bkgst,btkd->bskgd", probs.astype(v.dtype), v,
+                    preferred_element_type=jnp.float32)
+    return ot.reshape(B, S, H, v.shape[-1])
+
+
+def _cache_write(c, new, idx):
+    """Write ``new`` (B, S, ...) into cache ``c`` (B, T, ...) at each row's
+    own position ``idx[b]``."""
+    return jax.vmap(lambda cc, n, i: jax.lax.dynamic_update_slice_in_dim(
+        cc, n, i, axis=0))(c, new, idx)
+
+
+# --------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2)
+# --------------------------------------------------------------------------
+
+def mla_init(key, cfg, dtype) -> dict:
+    """``wq`` (D, H·(nope+rope)): per head the no-rope then the rope part;
+    ``wkva`` (D, rank+rope): the latent then the shared rope key; ``kv_norm``
+    the latent's RMSNorm; ``wkvb`` (rank, H·(nope+v)): per head the key's
+    no-rope part then the value; ``wo`` (H·v, D)."""
+    m, D, H = cfg.mla, cfg.d_model, cfg.n_heads
+    qk = m.qk_nope_head_dim + m.qk_rope_head_dim
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(ks[0], (D, H * qk), dtype),
+        "wkva": dense_init(ks[1], (D, m.kv_lora_rank + m.qk_rope_head_dim),
+                           dtype),
+        "kv_norm": rmsnorm_init(m.kv_lora_rank, dtype),
+        "wkvb": dense_init(ks[2], (m.kv_lora_rank,
+                                   H * (m.qk_nope_head_dim + m.v_head_dim)),
+                           dtype),
+        "wo": dense_init(ks[3], (H * m.v_head_dim, D), dtype,
+                         scale=1.0 / math.sqrt(H * m.v_head_dim)),
+    }
+
+
+def mla_scale(cfg) -> float:
+    """Softmax scale: ``(nope+rope)^-1/2``, times YaRN's ``mscale_all_dim``
+    factor squared where the rope is YaRN-scaled."""
+    m = cfg.mla
+    s = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    y = cfg.yarn
+    if y is not None and y.mscale_all_dim:
+        s *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return s
+
+
+def mla(p: dict, cfg, x: jax.Array, *, positions: jax.Array,
+        cache: Optional[dict] = None, advance: Optional[jax.Array] = None):
+    """Causal multi-head latent attention (DeepSeek-V2, no query
+    compression), keys and values decompressed per head.
+
+    Rope rotates the two halves of each rope part (``apply_rope``); the
+    published model rotates interleaved pairs, which is the same map once
+    the rope columns of ``wq`` and ``wkva`` are permuted (evens, then odds).
+    The cache (decode) holds each position's latent and rope key.  Returns
+    (out, new_cache)."""
+    m = cfg.mla
+    B, S, _ = x.shape
+    H, r = cfg.n_heads, m.kv_lora_rank
+    nope, rope, dv = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
+    q = jnp.einsum("bsd,dh->bsh", x, p["wq"].astype(x.dtype))
+    q = act(q.reshape(B, S, H, nope + rope), "batch", "seq", "heads", None)
+    kva = jnp.einsum("bsd,dh->bsh", x, p["wkva"].astype(x.dtype))
+    c = rmsnorm(p["kv_norm"], kva[..., :r], cfg.norm_eps)
+    cos, sin = rope_angles(positions, rope, cfg.rope_theta, yarn=cfg.yarn)
+    q = jnp.concatenate(
+        [q[..., :nope], apply_rope(q[..., nope:], cos, sin, rope)], axis=-1)
+    k_pe = apply_rope(kva[..., None, r:], cos, sin, rope)[:, :, 0]
+    new_cache = None
+    if cache is not None:
+        idx = cache["index"]
+        if advance is None:
+            advance = jnp.ones((B,), bool)
+        c = _cache_write(cache["ckv"], c, idx)
+        k_pe = _cache_write(cache["kpe"], k_pe, idx)
+        new_cache = {"ckv": c, "kpe": k_pe,
+                     "index": idx + jnp.where(advance, S, 0).astype(
+                         idx.dtype)}
+    T = c.shape[1]
+    kv = jnp.einsum("btr,rh->bth", c, p["wkvb"].astype(x.dtype))
+    kv = kv.reshape(B, T, H, nope + dv)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_pe[:, :, None, :],
+                                          (B, T, H, rope))], axis=-1)
+    k = act(k, "batch", "seq", "heads", None)
+    v = kv[..., nope:]
+    if cache is not None:
+        out = _attend_cached(q, k, v, cache["index"],
+                             mla_scale(cfg)).astype(x.dtype)
+    else:
+        out = _sdpa(q, k, v, causal=True, use_pallas=cfg.use_pallas,
+                    attn_chunk=cfg.attn_chunk, scale=mla_scale(cfg))
+    out = jnp.einsum("bsh,hd->bsd", out.reshape(B, S, H * dv),
+                     p["wo"].astype(x.dtype))
+    return act(out, "batch", "seq", "d"), new_cache
+
+
 def attention_cache(cfg, batch: int, max_len: int, dtype) -> dict:
+    if cfg.mla:  # the latent and the shared rope key of each position
+        m = cfg.mla
+        return {
+            "ckv": jnp.zeros((batch, max_len, m.kv_lora_rank), dtype),
+            "kpe": jnp.zeros((batch, max_len, m.qk_rope_head_dim), dtype),
+            "index": jnp.zeros((batch,), jnp.int32),
+        }
     K, hd = cfg.n_kv_heads, cfg.hd
     if cfg.kv_quant:  # int8 payload + per-(pos, head) scale: ~2x smaller
         return {

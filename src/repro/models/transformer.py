@@ -72,9 +72,10 @@ def _block_init(key, cfg, dtype, kind: str) -> dict:
     ninit, _ = layers.norm(cfg.norm)
     if kind in ("attn", "attn_moe", "shared_attn"):
         ks = jax.random.split(key, 2)
+        attn_init = layers.mla_init if cfg.mla else layers.attention_init
         p = {
             "norm1": ninit(cfg.d_model, dtype),
-            "attn": layers.attention_init(ks[0], cfg, dtype),
+            "attn": attn_init(ks[0], cfg, dtype),
             "norm2": ninit(cfg.d_model, dtype),
         }
         if kind == "attn_moe":
@@ -95,33 +96,40 @@ def _block_init(key, cfg, dtype, kind: str) -> dict:
 
 def _block_apply(p: dict, cfg, x, positions, kind: str, cache=None,
                  advance=None):
-    """Returns (x, aux, new_cache)."""
+    """Returns (x, aux, new_cache, held): ``held`` is the tokens routed to
+    each held expert of an expert layer that holds a share (else None)."""
     _, napply = layers.norm(cfg.norm)
     nfn = functools.partial(napply, eps=cfg.norm_eps)
     aux = jnp.asarray(0.0, jnp.float32)
+    held = None
     if kind in ("attn", "attn_moe", "shared_attn"):
         h = nfn(p["norm1"], x)
-        a_out, new_cache = layers.attention(
-            p["attn"], cfg, h, positions=positions, causal=True,
-            cache=cache, mrope=cfg.mrope, advance=advance)
+        if cfg.mla:
+            a_out, new_cache = layers.mla(p["attn"], cfg, h,
+                                          positions=positions, cache=cache,
+                                          advance=advance)
+        else:
+            a_out, new_cache = layers.attention(
+                p["attn"], cfg, h, positions=positions, causal=True,
+                cache=cache, mrope=cfg.mrope, advance=advance)
         x = x + a_out
         h2 = nfn(p["norm2"], x)
         if kind == "attn_moe":
-            f, aux = moe.moe_apply(p["moe"], cfg, h2)
+            f, aux, held = moe.moe_apply(p["moe"], cfg, h2)
         else:
             f = layers.mlp(p["mlp"], cfg, h2)
-        return x + f, aux, new_cache
+        return x + f, aux, new_cache, held
     if kind == "mamba":
         h = nfn(p["norm1"], x)
         if cache is None:
-            return x + mamba.mamba_apply(p["mamba"], cfg, h), aux, None
+            return x + mamba.mamba_apply(p["mamba"], cfg, h), aux, None, None
         if h.shape[1] > 1:  # prefill: full scan, then hand over the state
             out, new_cache = mamba.mamba_apply(p["mamba"], cfg, h,
                                                return_state=True)
         else:
             out, new_cache = mamba.mamba_decode_step(p["mamba"], cfg, h,
                                                      cache, advance=advance)
-        return x + out, aux, new_cache
+        return x + out, aux, new_cache, None
     raise ValueError(kind)
 
 
@@ -193,48 +201,57 @@ def _scan_segment(cfg, seg_params, x, positions, kind: str):
 
     def body(carry, lp):
         h, aux = carry
-        h2, a, _ = _block_apply(lp, cfg, h, positions, kind)
-        return (h2, aux + a), None
+        h2, a, _, held = _block_apply(lp, cfg, h, positions, kind)
+        return (h2, aux + a), held
 
     if cfg.remat == "full":
         body = jax.checkpoint(body, prevent_cse=False)
     carry = (x, jnp.asarray(0.0, jnp.float32))
     if cfg.scan_layers:
-        (x, aux), _ = jax.lax.scan(body, carry, seg_params)
+        (x, aux), held = jax.lax.scan(body, carry, seg_params)
     else:
         n = jax.tree_util.tree_leaves(seg_params)[0].shape[0]
+        helds = []
         for i in range(n):
             lp = jax.tree_util.tree_map(lambda l: l[i], seg_params)
-            carry, _ = body(carry, lp)
+            carry, h = body(carry, lp)
+            helds.append(h)
         x, aux = carry
-    return x, aux
+        held = None if helds[0] is None else jnp.stack(helds)
+    return x, aux, held
 
 
 def hidden_states(cfg, params, tokens, *, positions=None,
                   input_embeds=None):
-    """Backbone up to (and including) the final norm: (B,S,D), aux."""
+    """Backbone up to (and including) the final norm: (B,S,D), aux, and the
+    tokens routed to each held expert, (expert layers, n_held) int32, where
+    the expert layers hold a share (else None)."""
     x = (layers.embed(params["embedding"], cfg, tokens)
          if input_embeds is None else input_embeds)
     pos = _positions(cfg, tokens) if positions is None else positions
     aux_total = jnp.asarray(0.0, jnp.float32)
+    helds = []
     for (kind, count), seg_p in zip(structure(cfg), params["segments"]):
         if kind == "shared_attn":
-            x, a, _ = _block_apply(params["shared_block"], cfg, x, pos, kind)
+            x, a, _, _ = _block_apply(params["shared_block"], cfg, x, pos,
+                                      kind)
             aux_total = aux_total + a
         else:
-            x, a = _scan_segment(cfg, seg_p, x, pos, kind)
+            x, a, held = _scan_segment(cfg, seg_p, x, pos, kind)
             aux_total = aux_total + a
+            if held is not None:
+                helds.append(held)
     _, napply = layers.norm(cfg.norm)
     x = napply(params["final_norm"], x, eps=cfg.norm_eps)
-    return x, aux_total
+    return x, aux_total, (jnp.concatenate(helds) if helds else None)
 
 
 def forward(cfg, params, tokens, *, positions=None, input_embeds=None):
     """Full-sequence forward (train / prefill-without-cache).
 
     Returns (logits, aux_loss)."""
-    x, aux_total = hidden_states(cfg, params, tokens, positions=positions,
-                                 input_embeds=input_embeds)
+    x, aux_total, _ = hidden_states(cfg, params, tokens, positions=positions,
+                                    input_embeds=input_embeds)
     logits = layers.unembed(params["embedding"], cfg, x)
     return logits, aux_total
 
@@ -268,9 +285,15 @@ def _nll_chunked(cfg, params, hidden, labels):
     return total
 
 
-def loss_fn(cfg, params, batch, *, aux_weight: float = 0.01):
-    """batch: {"tokens": (B,S), "labels": (B,S)} → (loss, metrics)."""
-    hidden, aux = hidden_states(cfg, params, batch["tokens"])
+def loss_fn(cfg, params, batch, *, aux_weight: Optional[float] = None):
+    """batch: {"tokens": (B,S), "labels": (B,S)} → (loss, metrics).
+
+    ``aux_weight`` defaults to the config's balance-loss weight.  Where the
+    expert layers hold a share, ``metrics["held_expert_tokens"]`` counts the
+    tokens routed to each held expert, (expert layers, n_held) int32."""
+    if aux_weight is None:
+        aux_weight = cfg.moe.aux_weight if cfg.moe else 0.0
+    hidden, aux, held = hidden_states(cfg, params, batch["tokens"])
     labels = batch["labels"]
     B, S = labels.shape
     if cfg.loss_chunk and S > cfg.loss_chunk:
@@ -279,8 +302,11 @@ def loss_fn(cfg, params, batch, *, aux_weight: float = 0.01):
         total = _nll_dense(cfg, params, hidden, labels)
     nll = total / (B * S)
     loss = nll + aux_weight * aux
-    return loss, {"nll": nll, "aux": aux,
-                  "perplexity": jnp.exp(jnp.minimum(nll, 20.0))}
+    metrics = {"nll": nll, "aux": aux,
+               "perplexity": jnp.exp(jnp.minimum(nll, 20.0))}
+    if held is not None:
+        metrics["held_expert_tokens"] = held
+    return loss, metrics
 
 
 # --------------------------------------------------------------------------
@@ -306,8 +332,8 @@ def _scan_segment_cached(cfg, seg_params, seg_cache, x, positions, kind,
                          advance=None):
     def body(carry, pc):
         lp, lc = pc
-        h2, _, nc = _block_apply(lp, cfg, carry, positions, kind, cache=lc,
-                                 advance=advance)
+        h2, _, nc, _ = _block_apply(lp, cfg, carry, positions, kind,
+                                    cache=lc, advance=advance)
         return h2, nc
 
     if cfg.scan_layers:
@@ -340,8 +366,8 @@ def decode_step(cfg, params, cache, tokens, *, positions=None, advance=None):
     for (kind, count), seg_p, seg_c in zip(
             structure(cfg), params["segments"], cache["segments"]):
         if kind == "shared_attn":
-            x, _, nc = _block_apply(params["shared_block"], cfg, x, pos,
-                                    kind, cache=seg_c, advance=advance)
+            x, _, nc, _ = _block_apply(params["shared_block"], cfg, x, pos,
+                                       kind, cache=seg_c, advance=advance)
         else:
             x, nc = _scan_segment_cached(cfg, seg_p, seg_c, x, pos, kind,
                                          advance=advance)

@@ -33,6 +33,9 @@ LEAF_RULES: dict[str, tuple] = {
     "bq": ("heads",),
     "bk": ("heads",),
     "bv": ("heads",),
+    # latent attention
+    "wkva": ("d", None),
+    "wkvb": (None, "heads"),
     # mlp
     "gate": ("d", "ff"),
     "up": ("d", "ff"),
@@ -146,6 +149,8 @@ CACHE_RULES: dict[str, tuple] = {
     "v": ("batch", "kv_seq", "heads", None),
     "k_scale": ("batch", "kv_seq", "heads"),
     "v_scale": ("batch", "kv_seq", "heads"),
+    "ckv": ("batch", "kv_seq", None),
+    "kpe": ("batch", "kv_seq", None),
     "index": ("batch",),
     "conv": ("batch", None, "ff"),
     "h": ("batch", "heads", None, None),

@@ -98,6 +98,45 @@ class TestFlashAttention:
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-5)
 
+    @pytest.mark.parametrize("fn", ["mha", "mha_chunked"])
+    def test_value_head_dim_differs(self, rng, fn):
+        """Latent attention: keys of 192 against values of 128, against the
+        softmax written out."""
+        B, H, S, Dk, Dv = 1, 2, 64, 192, 128
+        q = jnp.asarray(rng.normal(size=(B, H, S, Dk)).astype(np.float32))
+        k = jnp.asarray(rng.normal(size=(B, H, S, Dk)).astype(np.float32))
+        v = jnp.asarray(rng.normal(size=(B, H, S, Dv)).astype(np.float32))
+        kw = {"chunk": 16} if fn == "mha_chunked" else {}
+        out = getattr(fa_ref, fn)(q, k, v, causal=True, scale=0.1, **kw)
+        s = np.einsum("bhqd,bhkd->bhqk", np.asarray(q, np.float64),
+                      np.asarray(k, np.float64)) * 0.1
+        s = np.where(np.tril(np.ones((S, S), bool)), s, -np.inf)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        want = np.einsum("bhqk,bhkd->bhqd", p, np.asarray(v, np.float64))
+        assert out.shape == (B, H, S, Dv)
+        np.testing.assert_allclose(np.asarray(out), want, rtol=1e-4,
+                                   atol=1e-4)
+
+    @pytest.mark.parametrize("dv", [192, 128])
+    def test_chunked_grad_equals_dense(self, rng, dv):
+        """The rematerialised chunk body changes nothing in the gradient."""
+        B, H, K, S, D = 1, 4, 2, 64, 192
+        q = jnp.asarray(rng.normal(size=(B, H, S, D)).astype(np.float32))
+        k = jnp.asarray(rng.normal(size=(B, K, S, D)).astype(np.float32))
+        v = jnp.asarray(rng.normal(size=(B, K, S, dv)).astype(np.float32))
+        w = jnp.asarray(rng.normal(size=(B, H, S, dv)).astype(np.float32))
+
+        def loss(f, **kw):
+            return lambda q, k, v: jnp.sum(f(q, k, v, causal=True, **kw) * w)
+
+        a = jax.grad(loss(fa_ref.mha), argnums=(0, 1, 2))(q, k, v)
+        b = jax.grad(loss(fa_ref.mha_chunked, chunk=16),
+                     argnums=(0, 1, 2))(q, k, v)
+        for x, y in zip(a, b):
+            np.testing.assert_allclose(np.asarray(x), np.asarray(y),
+                                       rtol=1e-4, atol=1e-4)
+
     @settings(max_examples=10, deadline=None)
     @given(sq=st.integers(1, 40), extra=st.integers(0, 40))
     def test_causality_property(self, sq, extra):

@@ -80,6 +80,17 @@ class TestFamilySpecific:
         assert structure(cfg)[0] == ("attn", 1)
         assert structure(cfg)[1] == ("attn_moe", 27)
 
+    def test_deepseek_v2_lite_structure_and_reduce(self):
+        """Layer 0 dense, then 26 MLA + held-expert layers; reduce() keeps
+        latent attention, YaRN, a held share and the balance loss."""
+        from repro.models.transformer import structure
+        cfg = get_config("deepseek-v2-lite")
+        assert structure(cfg) == [("attn", 1), ("attn_moe", 26)]
+        assert (cfg.moe.n_experts, cfg.moe.top_k, cfg.moe.n_held) == (64, 6, 8)
+        small = get_config("deepseek-v2-lite", reduced=True)
+        assert small.mla and small.yarn and small.moe.seq_aux
+        assert 0 < small.moe.n_held < small.moe.n_experts
+
     def test_zamba_shared_block_is_shared(self, key):
         """The shared attention block's params appear once (weight tying)."""
         cfg = get_config("zamba2-1.2b", reduced=True)
@@ -132,8 +143,8 @@ class TestFamilySpecific:
 
 
 class TestRaggedMoE:
-    """Ragged grouped-matmul MoE ≡ dropless capacity MoE (forward + grads
-    modulo the aux-loss grouping, which is per-group vs global)."""
+    """Ragged grouped-matmul MoE ≡ dropless capacity MoE (forward + grads;
+    both paths take the same per-sequence balance loss)."""
 
     @pytest.mark.parametrize("arch", ["deepseek-moe-16b",
                                       "phi3.5-moe-42b-a6.6b"])

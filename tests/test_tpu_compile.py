@@ -109,3 +109,51 @@ def test_mandelbrot(one_chip, hw):
     out = _compile(lambda: mb_ops.mandelbrot(H, W, pixel_delta=3.0 / W,
                                              max_iterations=1000), one_chip)
     assert out.out_info.shape == (H, W)
+
+
+def test_chunked_attention_backward_deepseek_v2_lite(one_chip):
+    """The training path's attention (XLA, query-chunked) at the DeepSeek
+    cell's shape, differentiated: no whole-row ``reduce-window`` (the TPU
+    compiler's form of a long masked softmax's max, which made a step some
+    forty times slower), and one chunk's probabilities at a time."""
+    from repro.kernels.flash_attention import ref as fa_ref
+    m = get_config("deepseek-v2-lite").mla
+    dk, dv = m.qk_nope_head_dim + m.qk_rope_head_dim, m.v_head_dim
+    B, H, S = 2, 16, 8192
+
+    def loss(q, k, v):
+        out = fa_ref.mha_chunked(q, k, v, causal=True, chunk=512)
+        return jnp.sum(out.astype(jnp.float32))
+
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+            for s in ((B, H, S, dk), (B, H, S, dk), (B, H, S, dv))]
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *args).compile()
+    assert "reduce-window" not in compiled.as_text()
+    # all of (B, H, S, S) probabilities in float32 would be 8.6 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
+def test_held_experts_backward_deepseek_v2_lite(one_chip):
+    """The held-expert layer (8 of 64, top 6) over a 2 x 8192 batch,
+    differentiated: the grouped matmuls compile to the TPU kernel."""
+    from repro.models import moe
+    cfg = get_config("deepseek-v2-lite")
+    m = cfg.moe
+    D, F = cfg.d_model, m.d_expert
+    f32 = jnp.float32
+
+    def loss(p, x):
+        y, aux, _ = moe.moe_apply_grouped(p, cfg, x)
+        return jnp.sum(y.astype(f32)) + aux
+
+    sds = lambda s, d=f32: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    p = {"router": sds((D, m.n_experts)),
+         "experts": {"gate": sds((m.n_held, D, F)),
+                     "up": sds((m.n_held, D, F)),
+                     "down": sds((m.n_held, F, D))},
+         "shared": {"gate": sds((D, 2 * F)), "up": sds((D, 2 * F)),
+                    "down": sds((2 * F, D))}}
+    compiled = jax.jit(jax.grad(loss)).lower(
+        p, sds((2, 8192, D), jnp.bfloat16)).compile()
+    assert "tpu_custom_call" in compiled.as_text()

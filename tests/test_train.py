@@ -92,6 +92,61 @@ class TestTrainStep:
         assert last < first - 0.25, (first, last)
 
 
+class TestTrainTrace:
+    """``train()`` records a ``data`` span and a ``train_step`` span per
+    step, and, where the expert layers hold a share, the tokens routed to
+    each held expert as a ``held_expert_tokens`` counter."""
+
+    def test_spans_and_held_expert_counter(self, key):
+        from repro.core.trace import TraceRecorder
+        cfg = get_config("deepseek-v2-lite", reduced=True)
+        model = Model(cfg)
+        src = SyntheticLM(batch=2, seq=16, vocab=cfg.vocab)
+        rec = TraceRecorder()
+        res = train(model, src, steps=3, key=key, recorder=rec)
+        ev = rec.events()
+        spans = [(e.name, e.args["step"]) for e in ev if e.kind == "span"]
+        assert spans == [(n, i) for i in range(3)
+                         for n in ("data", "train_step")]
+        counts = [e for e in ev if e.kind == "counter"]
+        assert [e.name for e in counts] == ["held_expert_tokens"] * 3
+        assert [e.args["step"] for e in counts] == [0, 1, 2]
+        held = np.asarray(counts[0].args["value"])
+        n_moe = cfg.n_layers - 1
+        assert held.shape == (n_moe, cfg.moe.n_held)
+        # each token routes k pairs in each expert layer; some land here
+        assert 0 < held.sum() <= n_moe * 2 * 16 * cfg.moe.top_k
+        step = [e for e in ev if e.name == "train_step"]
+        assert all(c.ts >= s.ts + s.dur for c, s in zip(counts, step))
+        assert res["step"] == 3 and "held_expert_tokens" not in \
+            res["history"][0]
+
+    def test_disabled_recorder_records_nothing(self, key):
+        """Tracing off, ``train`` records nothing (and so never waits on a
+        step's loss); the jitted step it returns runs again."""
+        from repro.core.trace import TraceRecorder
+        cfg = get_config("deepseek-v2-lite", reduced=True)
+        model = Model(cfg)
+        src = SyntheticLM(batch=2, seq=16, vocab=cfg.vocab)
+        rec = TraceRecorder(enabled=False)
+        res = train(model, src, steps=2, key=key, recorder=rec)
+        assert rec.events() == [] and res["step"] == 2
+        params, opt_state, metrics = res["step_fn"](
+            res["params"], res["opt_state"], src.create(2))
+        assert np.isfinite(float(metrics["loss"]))
+        assert int(opt_state["step"]) == 3
+
+    def test_on_step_ends_the_loop(self, key):
+        cfg = get_config("qwen2-0.5b", reduced=True)
+        model = Model(cfg)
+        src = SyntheticLM(batch=2, seq=16, vocab=cfg.vocab)
+        seen = []
+        res = train(model, src, steps=10, key=key,
+                    on_step=lambda i, *_: seen.append(i) or i == 2)
+        assert seen == [0, 1, 2] and res["step"] == 3
+        assert res["history"][-1]["step"] == 2
+
+
 class TestCheckpoint:
     def test_roundtrip_exact(self, key):
         tree = {"a": jnp.arange(12.0).reshape(3, 4),
