@@ -85,16 +85,19 @@ class TestFlashAttention:
                                    np.asarray(refv, np.float32),
                                    rtol=5e-2, atol=5e-2)
 
-    @pytest.mark.parametrize("shape", [
-        (1, 4, 2, 64, 64, 16, 16), (2, 2, 1, 96, 96, 8, 32),
-        (1, 2, 2, 40, 80, 16, 8)])
-    def test_chunked_equals_dense(self, rng, shape):
+    @pytest.mark.parametrize("shape,causal", [
+        pytest.param((1, 4, 2, 64, 64, 16, 16), True, id="shape0"),
+        pytest.param((2, 2, 1, 96, 96, 8, 32), True, id="shape1"),
+        pytest.param((1, 2, 2, 40, 80, 16, 8), True, id="shape2"),
+        pytest.param((1, 2, 2, 40, 80, 16, 8), False, id="full_extent"),
+        pytest.param((1, 2, 1, 32, 48, 16, 32), True, id="one_chunk")])
+    def test_chunked_equals_dense(self, rng, shape, causal):
         B, H, K, Sq, Sk, D, ck = shape
         q = jnp.asarray(rng.normal(size=(B, H, Sq, D)).astype(np.float32))
         k = jnp.asarray(rng.normal(size=(B, K, Sk, D)).astype(np.float32))
         v = jnp.asarray(rng.normal(size=(B, K, Sk, D)).astype(np.float32))
-        a = fa_ref.mha(q, k, v, causal=True)
-        b = fa_ref.mha_chunked(q, k, v, causal=True, chunk=ck)
+        a = fa_ref.mha(q, k, v, causal=causal)
+        b = fa_ref.mha_chunked(q, k, v, causal=causal, chunk=ck)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-5)
 
@@ -118,20 +121,27 @@ class TestFlashAttention:
         np.testing.assert_allclose(np.asarray(out), want, rtol=1e-4,
                                    atol=1e-4)
 
-    @pytest.mark.parametrize("dv", [192, 128])
-    def test_chunked_grad_equals_dense(self, rng, dv):
-        """The rematerialised chunk body changes nothing in the gradient."""
+    @pytest.mark.parametrize("dv,Sk,causal,chunk", [
+        pytest.param(192, 64, True, 16, id="192"),
+        pytest.param(128, 64, True, 16, id="128"),
+        pytest.param(128, 96, True, 16, id="longer_keys"),
+        pytest.param(128, 96, False, 16, id="full_extent"),
+        pytest.param(128, 64, True, 64, id="one_chunk")])
+    def test_chunked_grad_equals_dense(self, rng, dv, Sk, causal, chunk):
+        """The rematerialised chunks, each over its own key extent, change
+        nothing in the gradient."""
         B, H, K, S, D = 1, 4, 2, 64, 192
         q = jnp.asarray(rng.normal(size=(B, H, S, D)).astype(np.float32))
-        k = jnp.asarray(rng.normal(size=(B, K, S, D)).astype(np.float32))
-        v = jnp.asarray(rng.normal(size=(B, K, S, dv)).astype(np.float32))
+        k = jnp.asarray(rng.normal(size=(B, K, Sk, D)).astype(np.float32))
+        v = jnp.asarray(rng.normal(size=(B, K, Sk, dv)).astype(np.float32))
         w = jnp.asarray(rng.normal(size=(B, H, S, dv)).astype(np.float32))
 
         def loss(f, **kw):
-            return lambda q, k, v: jnp.sum(f(q, k, v, causal=True, **kw) * w)
+            return lambda q, k, v: jnp.sum(
+                f(q, k, v, causal=causal, **kw) * w)
 
         a = jax.grad(loss(fa_ref.mha), argnums=(0, 1, 2))(q, k, v)
-        b = jax.grad(loss(fa_ref.mha_chunked, chunk=16),
+        b = jax.grad(loss(fa_ref.mha_chunked, chunk=chunk),
                      argnums=(0, 1, 2))(q, k, v)
         for x, y in zip(a, b):
             np.testing.assert_allclose(np.asarray(x), np.asarray(y),

@@ -115,23 +115,28 @@ def test_chunked_attention_backward_deepseek_v2_lite(one_chip):
     """The training path's attention (XLA, query-chunked) at the DeepSeek
     cell's shape, differentiated: no whole-row ``reduce-window`` (the TPU
     compiler's form of a long masked softmax's max, which made a step some
-    forty times slower), and one chunk's probabilities at a time."""
+    forty times slower), one chunk's probabilities at a time, and under the
+    causal mask each chunk reads only the keys up to its diagonal (136 of
+    the 256 key blocks of 16 chunks, with the softmax's elementwise work)."""
     from repro.kernels.flash_attention import ref as fa_ref
     m = get_config("deepseek-v2-lite").mla
     dk, dv = m.qk_nope_head_dim + m.qk_rope_head_dim, m.v_head_dim
     B, H, S = 2, 16, 8192
-
-    def loss(q, k, v):
-        out = fa_ref.mha_chunked(q, k, v, causal=True, chunk=512)
-        return jnp.sum(out.astype(jnp.float32))
-
     args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
             for s in ((B, H, S, dk), (B, H, S, dk), (B, H, S, dv))]
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        *args).compile()
-    assert "reduce-window" not in compiled.as_text()
-    # all of (B, H, S, S) probabilities in float32 would be 8.6 GB
-    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+    flops = {}
+    for causal in (True, False):
+        def loss(q, k, v):
+            out = fa_ref.mha_chunked(q, k, v, causal=causal, chunk=512)
+            return jnp.sum(out.astype(jnp.float32))
+
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            *args).compile()
+        assert "reduce-window" not in compiled.as_text()
+        # all of (B, H, S, S) probabilities in float32 would be 8.6 GB
+        assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+        flops[causal] = compiled.cost_analysis()["flops"]
+    assert flops[True] <= 0.6 * flops[False], flops
 
 
 def test_held_experts_backward_deepseek_v2_lite(one_chip):
