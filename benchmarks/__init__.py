@@ -1,1 +1,1 @@
-"""Benchmark harness: paper tables T1-T10 + roofline extraction."""
+"""Benchmark harness: paper tables T1-T10, stream and cluster benchmarks."""

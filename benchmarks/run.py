@@ -1,7 +1,7 @@
-"""Benchmark runner: one function per paper table + the roofline report.
+"""Benchmark runner: one function per paper table.
 
 Prints ``name,us_per_call,derived`` CSV rows (scaffold contract).
-Usage: PYTHONPATH=src python -m benchmarks.run [--only tN] [--skip-roofline]
+Usage: PYTHONPATH=src python -m benchmarks.run [--only tN]
        PYTHONPATH=src python -m benchmarks.run --smoke   # small stream bench,
                                                          # writes BENCH_stream.json
 """
@@ -35,7 +35,6 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="substring filter on table fn names (e.g. t4)")
-    ap.add_argument("--skip-roofline", action="store_true")
     ap.add_argument("--smoke", action="store_true",
                     help="small stream benchmark only; writes BENCH_stream.json")
     args = ap.parse_args()
@@ -46,12 +45,9 @@ def main() -> None:
 
     from . import cluster as cluster_bench
     from . import tables
-    from . import roofline
     from . import stream as stream_bench
 
     fns = list(tables.ALL_TABLES) + [stream_bench.run, cluster_bench.run]
-    if not args.skip_roofline:
-        fns.append(roofline.run)
     print("name,us_per_call,derived")
     failures = 0
     for fn in fns:

@@ -19,8 +19,7 @@ can cut by *time*:
 
 Everything lands in a :class:`CostProfile` — cached per
 ``(process, shape, dtype)`` so re-calibrating an unchanged stage is free —
-which ``benchmarks/perf_report.py`` renders and
-``cost_assignment`` consumes.
+which ``cost_assignment`` consumes.
 """
 
 from __future__ import annotations

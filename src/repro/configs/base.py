@@ -2,8 +2,7 @@
 
 Every assigned architecture is a :class:`ModelConfig`; ``reduce()`` derives
 the CPU-smoke-test variant of the same family (small dims, same topology).
-Input shapes are :class:`ShapeConfig`; the four assigned shapes are module
-constants.  ``registry.py`` maps ``--arch`` ids to configs.
+``registry.py`` maps ``--arch`` ids to configs.
 """
 
 from __future__ import annotations
@@ -116,14 +115,10 @@ class ModelConfig:
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"
     remat: str = "full"  # none | full  (activation checkpointing per layer)
-    scan_layers: bool = True
     use_pallas: bool = False  # TPU kernels (interpret-validated on CPU)
-    # beyond-paper perf levers (see EXPERIMENTS.md §Perf)
-    seq_shard: bool = False  # shard sequence dim of activations (SP)
     moe_ragged: bool = False  # ragged grouped-matmul MoE path (vs capacity)
     loss_chunk: int = 0  # chunked cross-entropy (never materialise full
     # (B,S,V) logits); 0 = off
-    fsdp: bool = False  # ZeRO-3: shard weight contracting dims over 'data'
     kv_quant: bool = False  # int8 KV cache (per-position-head scales): ~2x
     # cache memory + bandwidth at decode
     attn_chunk: int = 0  # query-chunked attention: (S,S) logits never
@@ -133,13 +128,6 @@ class ModelConfig:
     @property
     def hd(self) -> int:
         return self.head_dim if self.head_dim else self.d_model // self.n_heads
-
-    def sub_quadratic(self) -> bool:
-        """Can this arch serve 500k+ context?  (SSM / hybrid trunks.)"""
-        return self.family in ("ssm", "hybrid")
-
-    def has_decode(self) -> bool:
-        return True  # all assigned archs have a decoder (whisper = enc-dec)
 
     def reduce(self) -> "ModelConfig":
         """Smoke-test variant: same family/topology, tiny dims."""
@@ -191,31 +179,3 @@ class ModelConfig:
                        "mla": MLAConfig, "yarn": YarnConfig}[k]
                 kw[k] = cls(**kw[k])
         return ModelConfig(**kw)
-
-
-# --------------------------------------------------------------------------
-
-
-@dataclasses.dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str  # train | prefill | decode
-
-
-TRAIN_4K = ShapeConfig("train_4k", 4_096, 256, "train")
-PREFILL_32K = ShapeConfig("prefill_32k", 32_768, 32, "prefill")
-DECODE_32K = ShapeConfig("decode_32k", 32_768, 128, "decode")
-LONG_500K = ShapeConfig("long_500k", 524_288, 1, "decode")
-
-SHAPES = (TRAIN_4K, PREFILL_32K, DECODE_32K, LONG_500K)
-SHAPES_BY_NAME = {s.name: s for s in SHAPES}
-
-
-def applicable(cfg: ModelConfig, shape: ShapeConfig) -> tuple[bool, str]:
-    """Is (arch × shape) a runnable cell?  (DESIGN.md §Arch-applicability)."""
-    if shape.name == "long_500k" and not cfg.sub_quadratic():
-        return False, ("full quadratic attention at 524k context is not "
-                       "servable; skipped per assignment note")
-    return True, ""

@@ -308,7 +308,7 @@ class CompiledNetwork:
         return self._step
 
     def lower(self, batch_spec):
-        """Lower (no execution) for dry-run / cost analysis."""
+        """Lower (no execution) for cost analysis."""
         return jax.jit(lambda b: self._trace(b)).lower(batch_spec)
 
     def make_batch(self, instances: int):

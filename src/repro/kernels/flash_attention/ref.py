@@ -57,8 +57,7 @@ def mha_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
     (Sq × Sk) logits never materialise — peak is (chunk × Sk) per chunk.
 
     The pure-XLA flash analogue for long prefill/train sequences (the Pallas
-    kernel is the TPU-native version; this one also fixes the dry-run's
-    memory picture since Mosaic kernels are opaque to the CPU backend).
+    kernel is the TPU-native version).
     Under ``causal`` each query chunk takes its logits, softmax and P·V over
     only the keys up to its diagonal, a static prefix, so n chunks compute
     (n+1)/2n of the key blocks; the mask then changes only the diagonal

@@ -15,7 +15,7 @@ zero-padding the assembled (TH + 2h, W) tile to (TH + 2h, W + 2h).
 
 The kernel taps are compile-time constants (closed over), so the loop over
 taps unrolls into 2·k² fused multiply-adds on the VPU — the MXU is not used
-(stencils are memory-bound; see EXPERIMENTS.md roofline for T6).
+(stencils are memory-bound).
 """
 
 from __future__ import annotations

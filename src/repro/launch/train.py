@@ -26,7 +26,7 @@ def main():
                     help="smoke-scale config (CPU-runnable)")
     ap.add_argument("--mesh", default="none",
                     choices=("none", "single", "multi"),
-                    help="production mesh (needs real devices or dry-run "
+                    help="production mesh (needs real devices or a "
                          "host-device override)")
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
@@ -54,9 +54,7 @@ def main():
     from repro.core import verify
     from .mesh import make_production_mesh, train_rules
 
-    import dataclasses
     cfg = get_config(args.arch, reduced=args.reduced)
-    cfg = dataclasses.replace(cfg, seq_shard=args.seq_shard)
     model = Model(cfg)
     opt = AdamW(lr=cosine_warmup(args.lr, warmup=max(args.steps // 20, 1),
                                  total=args.steps))
@@ -72,7 +70,7 @@ def main():
     ckpt = Checkpointer(args.ckpt_dir, async_save=True) \
         if args.ckpt_dir else None
 
-    rules = train_rules(cfg.seq_shard)
+    rules = train_rules(args.seq_shard)
     ctx = shard_ctx(mesh, rules) if mesh is not None else None
     if ctx:
         ctx.__enter__()

@@ -23,18 +23,6 @@ __all__ = ["init_params", "encode", "forward", "loss_fn", "init_cache",
            "decode_step"]
 
 
-def _maybe_scan(cfg, body, x, stacked):
-    """scan, or an unrolled loop when cfg.scan_layers=False (dry-run)."""
-    if cfg.scan_layers:
-        x, _ = jax.lax.scan(body, x, stacked)
-        return x
-    n = jax.tree_util.tree_leaves(stacked)[0].shape[0]
-    for i in range(n):
-        lp = jax.tree_util.tree_map(lambda l: l[i], stacked)
-        x, _ = body(x, lp)
-    return x
-
-
 def _sinusoid(length: int, d: int) -> jnp.ndarray:
     pos = jnp.arange(length, dtype=jnp.float32)[:, None]
     dim = jnp.arange(d // 2, dtype=jnp.float32)[None, :]
@@ -101,7 +89,7 @@ def encode(cfg, params, frames: jax.Array) -> jax.Array:
 
     if cfg.remat == "full":
         body = jax.checkpoint(body, prevent_cse=False)
-    x = _maybe_scan(cfg, body, x, params["enc"])
+    x, _ = jax.lax.scan(body, x, params["enc"])
     return layers.layernorm(params["enc_norm"], x)
 
 
@@ -139,7 +127,7 @@ def forward(cfg, params, tokens, *, frames: Optional[jax.Array] = None):
 
     if cfg.remat == "full":
         body = jax.checkpoint(body, prevent_cse=False)
-    x = _maybe_scan(cfg, body, x, params["dec"])
+    x, _ = jax.lax.scan(body, x, params["dec"])
     x = layers.layernorm(params["dec_norm"], x)
     logits = layers.unembed(params["embedding"], cfg, x)
     return logits, jnp.asarray(0.0, jnp.float32)
@@ -195,16 +183,7 @@ def decode_step(cfg, params, cache, tokens):
         h, nc = _dec_block(lp, cfg, h, enc_out, pos, cache=lc)
         return h, nc
 
-    if cfg.scan_layers:
-        x, new_self = jax.lax.scan(body, x, (params["dec"], cache["self"]))
-    else:
-        ncs = []
-        for i in range(cfg.n_layers):
-            pc = jax.tree_util.tree_map(lambda l: l[i],
-                                        (params["dec"], cache["self"]))
-            x, nc = body(x, pc)
-            ncs.append(nc)
-        new_self = jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *ncs)
+    x, new_self = jax.lax.scan(body, x, (params["dec"], cache["self"]))
     x = layers.layernorm(params["dec_norm"], x)
     logits = layers.unembed(params["embedding"], cfg, x)
     return logits, {"self": new_self, "enc_out": enc_out,

@@ -3,7 +3,7 @@
 Train/prefill uses the chunked SSD (kernels/ssd_scan: pure-jnp default,
 Pallas kernel when ``cfg.use_pallas``); decode is the O(1)-per-token
 recurrence on a carried (conv, ssd) state — the sub-quadratic property that
-lets mamba2/zamba2 serve the ``long_500k`` shape.
+lets mamba2/zamba2 serve 500k-token contexts.
 """
 
 from __future__ import annotations

@@ -10,8 +10,7 @@ gates).
 Dispatch follows the mesh-tf/MaxText "grouped capacity" scheme: each batch
 row is a group with capacity C = ceil(S · k / E · cf); dispatch/combine are
 (B, S, E, C) one-hots contracted with einsums — every tensor is shardable
-over (batch × expert) mesh axes, which is what makes the 16×16 dry-run
-tractable.
+over (batch × expert) mesh axes.
 
 The other path is dropless and grouped (:func:`moe_apply_grouped`, taken
 with ``moe_ragged`` or ``n_held``): token–expert pairs sorted by expert

@@ -193,11 +193,7 @@ def _positions(cfg, tokens, offset=0):
 
 
 def _scan_segment(cfg, seg_params, x, positions, kind: str):
-    """lax.scan over a stacked homogeneous segment (train/prefill path).
-
-    ``cfg.scan_layers=False`` unrolls the loop instead — used by the dry-run
-    because XLA cost analysis counts a while body once (exact accounting
-    needs the layers in the flat HLO), and available as a perf lever."""
+    """lax.scan over a stacked homogeneous segment (train/prefill path)."""
 
     def body(carry, lp):
         h, aux = carry
@@ -207,17 +203,7 @@ def _scan_segment(cfg, seg_params, x, positions, kind: str):
     if cfg.remat == "full":
         body = jax.checkpoint(body, prevent_cse=False)
     carry = (x, jnp.asarray(0.0, jnp.float32))
-    if cfg.scan_layers:
-        (x, aux), held = jax.lax.scan(body, carry, seg_params)
-    else:
-        n = jax.tree_util.tree_leaves(seg_params)[0].shape[0]
-        helds = []
-        for i in range(n):
-            lp = jax.tree_util.tree_map(lambda l: l[i], seg_params)
-            carry, h = body(carry, lp)
-            helds.append(h)
-        x, aux = carry
-        held = None if helds[0] is None else jnp.stack(helds)
+    (x, aux), held = jax.lax.scan(body, carry, seg_params)
     return x, aux, held
 
 
@@ -336,18 +322,7 @@ def _scan_segment_cached(cfg, seg_params, seg_cache, x, positions, kind,
                                     cache=lc, advance=advance)
         return h2, nc
 
-    if cfg.scan_layers:
-        x, new_cache = jax.lax.scan(body, x, (seg_params, seg_cache))
-    else:
-        n = jax.tree_util.tree_leaves(seg_params)[0].shape[0]
-        ncs = []
-        for i in range(n):
-            pc = jax.tree_util.tree_map(lambda l: l[i],
-                                        (seg_params, seg_cache))
-            x, nc = body(x, pc)
-            ncs.append(nc)
-        new_cache = jax.tree_util.tree_map(
-            lambda *ls: jnp.stack(ls), *ncs)
+    x, new_cache = jax.lax.scan(body, x, (seg_params, seg_cache))
     return x, new_cache
 
 

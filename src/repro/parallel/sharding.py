@@ -124,7 +124,7 @@ def _filter_axes(m, mesh):
 
 def param_specs(params: Any, mesh, rules: ShardingRules = ShardingRules()):
     """Pytree of PartitionSpec mirroring ``params`` (works on
-    ShapeDtypeStructs too — used by the dry-run)."""
+    ShapeDtypeStructs too)."""
     return jax.tree_util.tree_map_with_path(
         lambda path, leaf: _spec_for(path, leaf, rules, mesh), params)
 

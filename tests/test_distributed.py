@@ -143,9 +143,9 @@ def test_jaxmesh_hosts_compute_on_their_own_devices():
     assert placed == "[(0, [0]), (1, [1]), (2, [2])]"
 
 
-def test_reduced_model_dryrun_small_mesh():
-    """End-to-end mini dry-run: reduced config, (2,2) mesh, sharded params
-    lower+compile and the collective parser finds traffic."""
+def test_sharded_train_step_compiles_small_mesh():
+    """Reduced config on a (2,2) mesh: the sharded train step lowers,
+    compiles and communicates (its HLO holds a collective)."""
     out = _run("""
     import jax, jax.numpy as jnp, dataclasses
     from repro.configs import get_config
@@ -154,7 +154,6 @@ def test_reduced_model_dryrun_small_mesh():
     from repro.parallel.axes import shard_ctx, ShardingRules
     from repro.train.optimizer import AdamW
     from repro.train.train_loop import make_train_step
-    from repro.launch.dryrun import _collective_bytes
     from repro.launch.mesh import make_mesh
     mesh = make_mesh((2, 2), ("data", "model"))
     cfg = dataclasses.replace(get_config("qwen2-0.5b", reduced=True),
@@ -177,9 +176,11 @@ def test_reduced_model_dryrun_small_mesh():
         compiled = jax.jit(step, in_shardings=(p_sh, o_sh, b_sh),
                            out_shardings=(p_sh, o_sh, None)).lower(
             params_sds, opt_sds, batch).compile()
-    coll, kinds = _collective_bytes(compiled.as_text())
+    text = compiled.as_text()
     ma = compiled.memory_analysis()
-    print(coll > 0, ma.temp_size_in_bytes > 0)
+    print(any(k in text for k in ("all-reduce", "all-gather",
+                                  "reduce-scatter")),
+          ma.temp_size_in_bytes > 0)
     """, devices=4)
     assert out.split() == ["True", "True"]
 

@@ -32,14 +32,3 @@ def test_serve_cli():
                 "--requests", "4", "--slots", "2", "--max-new", "4"])
     assert "4 requests" in out
     assert "tok/s" in out
-
-
-def test_dryrun_cli_single_cell():
-    env = dict(_ENV)
-    out = subprocess.run(
-        [sys.executable, "-m", "repro.launch.dryrun", "--arch",
-         "whisper-tiny", "--shape", "decode_32k", "--mesh", "single"],
-        capture_output=True, text=True, timeout=560, env=env,
-        cwd=os.path.join(os.path.dirname(__file__), "..")).stdout
-    assert "whisper-tiny × decode_32k × 16x16" in out
-    assert "flops/dev" in out

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import ARCHS, SHAPES_BY_NAME, applicable, get_config
+from repro.configs import ARCHS, get_config
 from repro.models import Model
 
 ALL_ARCHS = sorted(ARCHS)
@@ -133,13 +133,6 @@ class TestFamilySpecific:
         assert cfg.rope_fraction == 0.5
         rot = int(cfg.hd * cfg.rope_fraction) // 2 * 2
         assert rot == cfg.hd // 2
-
-    def test_long_500k_applicability(self):
-        """DESIGN.md §Arch-applicability: only sub-quadratic archs serve
-        the 524k-context shape."""
-        shape = SHAPES_BY_NAME["long_500k"]
-        runnable = {a for a, c in ARCHS.items() if applicable(c, shape)[0]}
-        assert runnable == {"mamba2-2.7b", "zamba2-1.2b"}
 
 
 class TestRaggedMoE:

@@ -1,7 +1,7 @@
 """Sharding-rule properties: for every (arch × rules × mesh shape), the
 derived parameter/cache/batch specs are structurally valid — each mesh axis
 used at most once per spec, every sharded dim divisible by its axis product.
-This is the invariant that makes the 40-cell dry-run never hit a
+This is the invariant that keeps sharded lowering clear of a
 DuplicateSpecError or an indivisible shard."""
 
 import jax
@@ -32,7 +32,7 @@ MESHES = [
 RULES = [
     ShardingRules(),
     ShardingRules(seq="model"),
-    ShardingRules(d="data"),  # fsdp
+    ShardingRules(d="data"),  # weights sharded over data (ZeRO-3)
     ShardingRules(heads=None, ff=None, d=("data", "model"),
                   batch=("pod", "data", "model")),  # flattened pure DP
     ShardingRules(kv_seq="model"),  # serve
