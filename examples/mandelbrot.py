@@ -25,7 +25,7 @@ CHARS = " .:-=+*#%@"
 def make_net(width: int, height: int, bands: int, iters: int):
     """Module-level factory: the cluster's pipe transport spawns fresh
     interpreters that rebuild the network from this picklable recipe."""
-    import jax
+    from repro.kernels.mandelbrot import ops as mb_ops
 
     band_h = height // bands
     delta = 3.0 / width
@@ -35,22 +35,9 @@ def make_net(width: int, height: int, bands: int, iters: int):
         return jnp.asarray(i * band_h, jnp.int32)
 
     def render_band(row0):
-        ys = -1.15 + delta * (row0 + jnp.arange(band_h, dtype=jnp.float32))
-        xs = -2.2 + delta * jnp.arange(width, dtype=jnp.float32)
-        cr = jnp.broadcast_to(xs[None, :], (band_h, width))
-        ci = jnp.broadcast_to(ys[:, None], (band_h, width))
-
-        def body(_, st):
-            zr, zi, cnt = st
-            zr2, zi2 = zr * zr, zi * zi
-            inside = (zr2 + zi2) <= 4.0
-            return (jnp.where(inside, zr2 - zi2 + cr, zr),
-                    jnp.where(inside, 2 * zr * zi + ci, zi),
-                    cnt + inside.astype(jnp.int32))
-
-        z0 = jnp.zeros((band_h, width), jnp.float32)
-        _, _, cnt = jax.lax.fori_loop(
-            0, iters, body, (z0, z0, jnp.zeros((band_h, width), jnp.int32)))
+        cnt, _ = mb_ops.mandelbrot_band(row0, rows=band_h, width=width,
+                                        x0=-2.2, y0=-1.15, pixel_delta=delta,
+                                        max_iterations=iters)
         return (row0, cnt)
 
     def collector(acc, item):
@@ -179,12 +166,12 @@ def main():
                           for c in range(W)))
 
     if not args.hosts:
-        # Pallas kernel cross-check on the full image (interpreted on CPU)
-        from repro.kernels.mandelbrot import ops as mb_ops
+        # the plain escape-time reference on the full image
+        from repro.kernels.mandelbrot import ref as mb_ref
         delta = 3.0 / W
-        full = mb_ops.mandelbrot(H, W, x0=-2.2, y0=-1.15, pixel_delta=delta,
+        full = mb_ref.mandelbrot(H, W, x0=-2.2, y0=-1.15, pixel_delta=delta,
                                  max_iterations=args.iters)
-        print(f"pallas kernel == farm image: "
+        print(f"plain reference == farm image: "
               f"{bool((np.asarray(full) == img).all())}")
 
 

@@ -226,6 +226,43 @@ class TestSSD:
 # mandelbrot
 # --------------------------------------------------------------------------
 
+def _harness_reference():
+    """The farm cell's float32 reference (absolute rows), loaded by path."""
+    import importlib.util
+    from pathlib import Path
+    path = (Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+            / "references" / "mandelbrot.py")
+    spec = importlib.util.spec_from_file_location("mandelbrot_reference",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+mb_harness_ref = _harness_reference()
+
+# bands as the farm renders them: (top row, rows, width, iters).  Widths off
+# the 128-lane tiling, bands shorter than a tile, top rows on both sides of
+# the image, iteration counts off the check block.  y0 is chosen so that no
+# row's ci is exactly zero: there a pixel sits on the real axis, where an
+# FMA contraction's one ulp flips escape counts
+BANDS = [(0, 8, 32, 20), (-37, 5, 100, 150), (123, 13, 1000, 60),
+         (-8, 64, 256, 200), (301, 3, 128, 128), (-64, 16, 640, 50),
+         (40, 24, 384, 64)]
+BAND_KW = dict(x0=-2.0, y0=-1.0123)
+
+
+def _band(row0, rows, width, iters, **kw):
+    kw = {**BAND_KW, "pixel_delta": 2.0 / width, **kw}
+    counts, ran = mb_ops.mandelbrot_band(jnp.int32(row0), rows=rows,
+                                         width=width, max_iterations=iters,
+                                         **kw)
+    want = mb_harness_ref.counts(
+        jnp.asarray(row0 + np.arange(rows), jnp.int32), width, kw["x0"],
+        kw["y0"], kw["pixel_delta"], iters)
+    return np.asarray(counts), np.asarray(ran), np.asarray(want)
+
+
 class TestMandelbrot:
     @pytest.mark.parametrize("hw", [(64, 100), (40, 64), (8, 16)])
     def test_vs_ref(self, hw):
@@ -238,12 +275,71 @@ class TestMandelbrot:
         refv = mb_ref.mandelbrot(H, W, x0=-2.0, y0=-1.0123,
                                  pixel_delta=2.0 / W, max_iterations=64)
         same = np.asarray(out) == np.asarray(refv)
-        assert same.mean() > 0.999, f"{(~same).sum()} boundary pixels differ"
+        assert same.all(), f"{(~same).sum()} pixels differ"
 
     def test_interior_hits_escape_value(self):
         out = mb_ops.mandelbrot(64, 64, x0=-1.0, y0=-0.5,
                                 pixel_delta=1.0 / 64, max_iterations=50)
         assert int((np.asarray(out) == 50).sum()) > 0  # interior present
+
+    @pytest.mark.parametrize("row0,rows,width,iters", BANDS)
+    def test_band_vs_reference(self, row0, rows, width, iters):
+        """The band kernel's counts equal the float32 reference's, bit for
+        bit, whatever the tile a pixel lands in stopped at."""
+        counts, _, want = _band(row0, rows, width, iters)
+        assert counts.shape == want.shape == (rows, width)
+        np.testing.assert_array_equal(counts, want)
+
+    @pytest.mark.parametrize("row0,rows,width,iters", BANDS)
+    def test_tile_iterations(self, row0, rows, width, iters):
+        """Each tile runs to the first check at or after its slowest
+        pixel's count: the checks come after the ``iters % CHECK_EVERY``
+        odd steps and after each block of ``CHECK_EVERY`` from there, up to
+        ``iters``.  Pad pixels never hold a tile back."""
+        from repro.kernels.mandelbrot import kernel
+        _, ran, want = _band(row0, rows, width, iters)
+        tw, blk = kernel.tile_width(width), kernel.CHECK_EVERY
+        th = kernel.TILE_H
+        hp, wp = -(-rows // th) * th, -(-width // tw) * tw
+        padded = np.zeros((hp, wp), np.int64)
+        padded[:rows, :width] = want
+        slowest = padded.reshape(hp // th, th, wp // tw, tw).max(axis=(1, 3))
+        odd = iters % blk
+        rule = odd + blk * np.maximum(-(-(slowest - odd) // blk), 0)
+        assert rule.max() <= iters
+        np.testing.assert_array_equal(ran, rule)
+
+    @pytest.mark.parametrize("where", ["outside", "inside"])
+    def test_whole_tile_stop(self, where):
+        """A tile wholly outside the set stops at its first check; a tile
+        wholly inside it runs every iteration."""
+        from repro.kernels.mandelbrot import kernel
+        iters = 200
+        kw = (dict(x0=2.5, y0=0.5, pixel_delta=1e-3) if where == "outside"
+              else dict(x0=-0.3, y0=0.05, pixel_delta=1e-4))
+        counts, ran, want = _band(0, 16, 256, iters, **kw)
+        np.testing.assert_array_equal(counts, want)
+        if where == "outside":
+            assert (counts == 1).all()
+            first_check = iters % kernel.CHECK_EVERY or kernel.CHECK_EVERY
+            assert (ran == first_check).all()
+        else:
+            assert (counts == iters).all() and (ran == iters).all()
+
+    def test_band_under_vmap(self):
+        """The farm renders its bands through ``vmap`` with the top row
+        traced: each band equals the band rendered alone."""
+        rows, width, iters = 8, 100, 50
+        kw = dict(x0=-2.0, y0=-1.0123, pixel_delta=2.0 / width,
+                  max_iterations=iters)
+        tops = jnp.asarray([-40, -8, 0, 24, 77], jnp.int32)
+        counts, ran = jax.vmap(lambda r: mb_ops.mandelbrot_band(
+            r, rows=rows, width=width, **kw))(tops)
+        for b, top in enumerate(np.asarray(tops)):
+            one, one_ran = mb_ops.mandelbrot_band(top, rows=rows,
+                                                  width=width, **kw)
+            np.testing.assert_array_equal(counts[b], one)
+            np.testing.assert_array_equal(ran[b], one_ran)
 
 
 # --------------------------------------------------------------------------

@@ -111,6 +111,18 @@ def test_mandelbrot(one_chip, hw):
     assert out.out_info.shape == (H, W)
 
 
+def test_mandelbrot_farm_bands(one_chip):
+    """The farm's worker stage at the cell's shape: 16 bands of 64 rows by
+    4096 under ``vmap``, the top rows traced, 1000 iterations."""
+    bands, rows, W = 16, 64, 4096
+    out = _compile(jax.vmap(lambda r: mb_ops.mandelbrot_band(
+        r, rows=rows, width=W, x0=-2.2, y0=-1.15, pixel_delta=3.0 / W,
+        max_iterations=1000)), one_chip, ((bands,), jnp.int32))
+    counts, ran = out.out_info
+    assert counts.shape == (bands, rows, W)
+    assert ran.shape == (bands, rows // 8, W // 512)
+
+
 def test_chunked_attention_backward_deepseek_v2_lite(one_chip):
     """The training path's attention (XLA, query-chunked) at the DeepSeek
     cell's shape, differentiated: no whole-row ``reduce-window`` (the TPU
