@@ -30,6 +30,18 @@ class MoEConfig:
     # in place of the switch-style loss on the top-1 choice
     seq_aux: bool = False
     aux_weight: float = 0.01  # the balance loss's weight in the total loss
+    # softmax | sigmoid: sigmoid scores choose the top-k by score plus a
+    # correction bias that no gradient moves (DeepSeek-V3, Nemotron-H)
+    router: str = "softmax"
+    routed_scale: float = 1.0  # the routed experts' weights times this
+    d_shared: int = 0  # the shared experts' width; 0 = n_shared * d_expert
+    # the correction bias's step: b_i += rate * sign(mean(load) - load_i)
+    # after each training step, from the step's tokens per expert
+    bias_rate: float = 0.0
+
+    @property
+    def shared_width(self) -> int:
+        return self.d_shared or self.n_shared * self.d_expert
 
 
 @dataclasses.dataclass(frozen=True)
@@ -62,11 +74,12 @@ class SSMConfig:
     head_dim: int = 64
     expand: int = 2
     conv_kernel: int = 4
-    n_groups: int = 1
+    n_groups: int = 1  # B and C state groups; the gated norm's groups too
     chunk: int = 64
+    heads: int = 0  # 0 = expand * d_model // head_dim
 
     def n_heads(self, d_model: int) -> int:
-        return (self.expand * d_model) // self.head_dim
+        return self.heads or (self.expand * d_model) // self.head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -95,7 +108,7 @@ class ModelConfig:
     d_ff: int
     vocab: int
     head_dim: Optional[int] = None  # default d_model // n_heads
-    act: str = "swiglu"  # swiglu | geglu | gelu
+    act: str = "swiglu"  # swiglu | geglu | gelu | relu2 (down(relu(up x)^2))
     qkv_bias: bool = False
     rope_theta: float = 10_000.0
     rope_fraction: float = 1.0  # glm4 rotates half the head dim
@@ -111,6 +124,9 @@ class ModelConfig:
     yarn: Optional[YarnConfig] = None
     mrope: bool = False
     mrope_sections: Tuple[int, int, int] = (16, 24, 24)
+    # one mixer per layer, in order (Nemotron-H's hybrid_override_pattern):
+    # M Mamba-2, E expert layer, * attention; "" = the family's structure
+    pattern: str = ""
     # numerics / execution
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"
@@ -135,8 +151,13 @@ class ModelConfig:
         hd = 16
         n_heads = max(2, min(4, self.n_heads))
         n_kv = 1 if self.n_kv_heads == 1 else min(2, n_heads)
+        n_layers = max(2, min(4, self.n_layers))
+        if self.pattern:  # the shortest prefix that holds every kind
+            n_layers = max(self.pattern.index(c)
+                           for c in set(self.pattern)) + 1
         kw.update(
-            n_layers=max(2, min(4, self.n_layers)),
+            n_layers=n_layers,
+            pattern=self.pattern[:n_layers],
             d_model=n_heads * 32,
             n_heads=n_heads,
             n_kv_heads=n_kv,
@@ -148,21 +169,20 @@ class ModelConfig:
             remat="none",
         )
         if self.moe:
-            kw["moe"] = MoEConfig(
-                n_experts=4, top_k=2, d_expert=64,
+            kw["moe"] = dataclasses.replace(
+                self.moe, n_experts=4, top_k=2, d_expert=64,
                 n_shared=min(1, self.moe.n_shared),
-                layer0_dense=self.moe.layer0_dense,
+                d_shared=self.moe.d_shared and 128,
                 # dropless at smoke scale so forward ≡ prefill+decode
                 capacity_factor=4.0,
-                router_norm_topk=self.moe.router_norm_topk,
-                n_held=2 if self.moe.n_held else 0,
-                seq_aux=self.moe.seq_aux, aux_weight=self.moe.aux_weight)
+                n_held=2 if self.moe.n_held else 0)
         if self.mla:
             kw["mla"] = MLAConfig(kv_lora_rank=32, qk_nope_head_dim=16,
                                   qk_rope_head_dim=8, v_head_dim=16)
         if self.ssm:
             kw["ssm"] = SSMConfig(d_state=16, head_dim=16,
                                   expand=2, conv_kernel=4,
+                                  n_groups=min(2, self.ssm.n_groups),
                                   chunk=16)
         if self.hybrid:
             kw["hybrid"] = HybridConfig(period=2,

@@ -2,7 +2,6 @@
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from . import ref
@@ -14,30 +13,23 @@ def ssd(x, dt, A, B, C, *, chunk: int = 64, use_pallas: bool = False,
     """Multi-head SSD.
 
     x: (batch, S, H, P); dt: (batch, S, H); A: (H,);
-    B, C: (batch, S, G, N) with G ∈ {1, H} (state groups broadcast to heads).
+    B, C: (batch, S, G, N) with G | H: head h reads state group h // (H/G).
     Returns y: (batch, S, H, P); with ``return_state`` also the final state
-    (batch·H, N, P) (jnp path — used by prefill).
+    (batch, H, N, P) (jnp path — used by prefill).
     """
     b, S, H, P = x.shape
-    G, N = B.shape[2], B.shape[3]
-    xf = jnp.moveaxis(x, 2, 1).reshape(b * H, S, P)
-    dtf = jnp.moveaxis(dt, 2, 1).reshape(b * H, S)
-    if G == 1:
-        Bf = jnp.broadcast_to(B, (b, S, H, N))
-        Cf = jnp.broadcast_to(C, (b, S, H, N))
-    else:
-        Bf, Cf = B, C
-    Bf = jnp.moveaxis(Bf, 2, 1).reshape(b * H, S, N)
-    Cf = jnp.moveaxis(Cf, 2, 1).reshape(b * H, S, N)
-    Af = jnp.tile(A, b)  # (b*H,) — head h of every batch row
     ch = chunk if S % chunk == 0 else S
-    if use_pallas and not return_state:
-        a_log = dtf * Af[:, None]
-        y = ssd_scan(xf, dtf, a_log, Bf, Cf, chunk=ch)
-        hT = None
-    else:
-        y, hT = ref.ssd_chunked(xf, dtf, Af, Bf, Cf, chunk=ch)
-    out = jnp.moveaxis(y.reshape(b, H, S, P), 1, 2)
-    if return_state:
-        return out, hT
-    return out
+    if not use_pallas or return_state:
+        y, hT = ref.ssd_chunked(x, dt, A, B, C, chunk=ch)
+        return (y, hT) if return_state else y
+    # the kernel takes one sequence per (batch, head), its groups repeated
+    rep = H // B.shape[2]
+
+    def fold(a):
+        return jnp.moveaxis(a, 2, 1).reshape((b * H, S) + a.shape[3:])
+
+    xf, dtf = fold(x), fold(dt)
+    Bf, Cf = (fold(jnp.repeat(m, rep, axis=2)) for m in (B, C))
+    a_log = dtf * jnp.tile(A, b)[:, None]  # head h of every batch row
+    y = ssd_scan(xf, dtf, a_log, Bf, Cf, chunk=ch)
+    return jnp.moveaxis(y.reshape(b, H, S, P), 1, 2)

@@ -6,8 +6,9 @@
     y_t = C_t · h_t
 
 ``ssd_chunked`` — the SSD chunked form (intra-chunk dual "attention" matmuls
-+ inter-chunk state recurrence), pure jnp; this is the model's default path
-and is algebraically identical to ``ssd_naive``.
++ inter-chunk state recurrence, one scan step a chunk) over heads that share
+B and C in state groups, pure jnp; this is the model's default path and is
+algebraically identical to ``ssd_naive`` on each head.
 """
 
 from __future__ import annotations
@@ -45,46 +46,60 @@ def ssd_naive(x, dt, A, B, C):
 
 
 def ssd_chunked(x, dt, A, B, C, *, chunk: int = 64):
-    """Chunked SSD, same contract as ssd_naive.  S % chunk == 0 required."""
-    BH, S, P = x.shape
-    N = B.shape[-1]
-    assert S % chunk == 0, (S, chunk)
-    nc = S // chunk
-    xf = x.astype(jnp.float32).reshape(BH, nc, chunk, P)
-    dtf = dt.astype(jnp.float32).reshape(BH, nc, chunk)
-    Bf = B.astype(jnp.float32).reshape(BH, nc, chunk, N)
-    Cf = C.astype(jnp.float32).reshape(BH, nc, chunk, N)
-    a = dtf * A.astype(jnp.float32)[:, None, None]  # (BH, nc, L) log-decays
-    cum = jnp.cumsum(a, axis=-1)  # inclusive
-    total = cum[..., -1]
+    """Chunked SSD over state groups.  x: (b, S, H, P); dt: (b, S, H);
+    A: (H,) (negative); B, C: (b, S, G, N) with G | H: head h reads group
+    h // (H/G).  S % chunk == 0 required.  Returns y: (b, S, H, P) and the
+    final state (b, H, N, P).
 
-    # intra-chunk: y[t] = sum_{s<=t} exp(cum t - cum s) dt_s (C_t·B_s) x_s
-    G = jnp.einsum("bctn,bcsn->bcts", Cf, Bf)
-    decay = jnp.exp(cum[..., :, None] - cum[..., None, :])
-    mask = jnp.tril(jnp.ones((chunk, chunk), bool))
-    W = jnp.where(mask, G * decay, 0.0) * dtf[..., None, :]
-    y_intra = jnp.einsum("bcts,bcsp->bctp", W, xf)
+    One scan step a chunk, rematerialised: the chunk's outputs from its
+    intra-chunk "attention" (``C_t . B_s`` once per group) and the state
+    entering it, then the state leaving it.  Only that state is kept
+    between steps, so the memory is one chunk's, forward and backward.
+    Within a chunk the decay from s to t is exp(cum_t - cum_s); the
+    exponent is masked to -inf above the diagonal *before* exp, so no
+    entry overflows and the gradient stays finite however large a chunk's
+    summed log-decay grows."""
+    b, S, H, P = x.shape
+    G, N = B.shape[2], B.shape[3]
+    R, L = H // G, chunk
+    assert S % L == 0 and H % G == 0, (S, L, H, G)
+    nc = S // L
+    f32 = jnp.float32
 
-    # chunk state contributions: Z_c = sum_s exp(total - cum s) dt_s B_s⊗x_s
-    w_state = jnp.exp(total[..., None] - cum) * dtf  # (BH, nc, L)
-    Z = jnp.einsum("bcsn,bcs,bcsp->bcnp", Bf, w_state, xf)
+    def chunks(a, *tail):  # (b, S, ...) -> (nc, b, L, ...)
+        return jnp.moveaxis(a.reshape((b, nc, L) + tail), 1, 0)
 
-    # inter-chunk recurrence over nc: h_c = exp(total_c) h_{c-1} + Z_c
-    def step(h, inp):
-        tot, z = inp
-        h_out = h  # state *entering* the chunk
-        h = jnp.exp(tot)[:, None, None] * h + z
-        return h, h_out
+    xs = chunks(x, G, R, P)
+    dts = chunks(dt.astype(f32), G, R)
+    Bs, Cs = chunks(B, G, N), chunks(C, G, N)
+    Ar = A.astype(f32).reshape(G, R)
+    causal = jnp.tril(jnp.ones((L, L), bool))
 
-    h0 = jnp.zeros((BH, N, P), jnp.float32)
-    hT, h_in = jax.lax.scan(
-        step, h0, (jnp.moveaxis(total, 1, 0), jnp.moveaxis(Z, 1, 0)))
-    h_in = jnp.moveaxis(h_in, 0, 1)  # (BH, nc, N, P)
+    def step(h, inp):  # h: (b, G, R, N, P), the state entering the chunk
+        xc, dtc, Bc, Cc = inp
+        xc, Bc, Cc = (v.astype(f32) for v in (xc, Bc, Cc))
+        dtc = jnp.moveaxis(dtc, 1, -1)  # (b, G, R, L)
+        cum = jnp.cumsum(dtc * Ar[..., None], axis=-1)  # inclusive
+        total = cum[..., -1]  # (b, G, R)
+        # y[t] = sum_{s<=t} exp(cum t - cum s) dt_s (C_t.B_s) x_s
+        #        + (C_t exp(cum t)) . h
+        CB = jnp.einsum("btgn,bsgn->bgts", Cc, Bc)
+        seg = jnp.where(causal, cum[..., :, None] - cum[..., None, :],
+                        -jnp.inf)
+        W = CB[:, :, None] * jnp.exp(seg) * dtc[..., None, :]
+        y = (jnp.einsum("bgrts,bsgrp->btgrp", W, xc)
+             + jnp.einsum("btgn,bgrt,bgrnp->btgrp", Cc, jnp.exp(cum), h))
+        # h' = exp(total) h + sum_s exp(total - cum s) dt_s B_s (x) x_s
+        w_state = jnp.exp(total[..., None] - cum) * dtc
+        h = (jnp.exp(total)[..., None, None] * h
+             + jnp.einsum("bsgn,bgrs,bsgrp->bgrnp", Bc, w_state, xc))
+        return h, y.astype(x.dtype)
 
-    # inter-chunk output: y[t] += (C_t * exp(cum t)) · h_in
-    y_inter = jnp.einsum("bctn,bct,bcnp->bctp", Cf, jnp.exp(cum), h_in)
-    y = (y_intra + y_inter).reshape(BH, S, P)
-    return y.astype(x.dtype), hT
+    step = jax.checkpoint(step, prevent_cse=False)
+    hT, ys = jax.lax.scan(step, jnp.zeros((b, G, R, N, P), f32),
+                          (xs, dts, Bs, Cs))
+    y = jnp.moveaxis(ys, 0, 1).reshape(b, S, H, P)
+    return y, hT.reshape(b, H, N, P)
 
 
 def ssd_decode_step(h, x_t, dt_t, A, B_t, C_t):

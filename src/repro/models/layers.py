@@ -403,6 +403,11 @@ def mlp_init(key, cfg, dtype, d_ff: Optional[int] = None) -> dict:
     D = cfg.d_model
     F = d_ff if d_ff is not None else cfg.d_ff
     ks = jax.random.split(key, 3)
+    if cfg.act == "relu2":  # not gated (Nemotron-H)
+        return {
+            "up": dense_init(ks[0], (D, F), dtype),
+            "down": dense_init(ks[1], (F, D), dtype, scale=1.0 / math.sqrt(F)),
+        }
     if cfg.act in ("swiglu", "geglu"):
         return {
             "gate": dense_init(ks[0], (D, F), dtype),
@@ -417,6 +422,11 @@ def mlp_init(key, cfg, dtype, d_ff: Optional[int] = None) -> dict:
     }
 
 
+def relu2(x: jax.Array) -> jax.Array:
+    """Squared ReLU."""
+    return jnp.square(jax.nn.relu(x))
+
+
 def mlp(p: dict, cfg, x: jax.Array, *, act_fn: Optional[str] = None):
     kind = act_fn or cfg.act
     if kind in ("swiglu", "geglu"):
@@ -426,6 +436,10 @@ def mlp(p: dict, cfg, x: jax.Array, *, act_fn: Optional[str] = None):
         u = act(u, "batch", "seq", "ff")
         h = (jax.nn.silu(g) if kind == "swiglu"
              else jax.nn.gelu(g, approximate=True)) * u
+        out = jnp.einsum("bsf,fd->bsd", h, p["down"].astype(x.dtype))
+    elif kind == "relu2":
+        h = jnp.einsum("bsd,df->bsf", x, p["up"].astype(x.dtype))
+        h = relu2(act(h, "batch", "seq", "ff"))
         out = jnp.einsum("bsf,fd->bsd", h, p["down"].astype(x.dtype))
     else:
         h = jnp.einsum("bsd,df->bsf", x, p["up"].astype(x.dtype))

@@ -23,8 +23,8 @@ __all__ = ["mamba_init", "mamba_apply", "mamba_cache", "mamba_decode_step"]
 
 def _dims(cfg):
     s = cfg.ssm
-    di = s.expand * cfg.d_model
-    H = di // s.head_dim
+    H = s.n_heads(cfg.d_model)
+    di = H * s.head_dim
     conv_dim = di + 2 * s.n_groups * s.d_state
     return di, H, s.head_dim, s.d_state, s.n_groups, conv_dim, s.conv_kernel
 
@@ -84,21 +84,31 @@ def mamba_apply(p: dict, cfg, x: jax.Array, *, return_state: bool = False):
     dt = jax.nn.softplus(dt.astype(jnp.float32)
                          + p["dt_bias"].astype(jnp.float32))
     A = -jnp.exp(p["A_log"].astype(jnp.float32))  # (H,)
-    res = ssd_ops.ssd(xs, dt, A, Bm, Cm, chunk=cfg.ssm.chunk,
-                      use_pallas=cfg.use_pallas,
-                      return_state=return_state)
+    with jax.named_scope("ssd"):
+        res = ssd_ops.ssd(xs, dt, A, Bm, Cm, chunk=cfg.ssm.chunk,
+                          use_pallas=cfg.use_pallas,
+                          return_state=return_state)
     y, hT = res if return_state else (res, None)
     y = y + p["D_skip"].astype(jnp.float32)[None, None, :, None] * xs
     y = y.astype(x.dtype).reshape(B, S, di)
-    # gated RMSNorm (mamba2): norm(y * silu(z))
-    y = layers.rmsnorm(p["gate_norm"], y * jax.nn.silu(z), eps=cfg.norm_eps)
+    y = _gated_norm(p["gate_norm"], cfg, y, z)
     out = jnp.einsum("bsk,kd->bsd", y, p["out_proj"].astype(x.dtype))
     out = act(out, "batch", "seq", "d")
     if return_state:
         pad = jnp.zeros((B, ck - 1, conv_dim), xBC_raw.dtype)
         conv_state = jnp.concatenate([pad, xBC_raw], axis=1)[:, -(ck - 1):]
-        return out, {"conv": conv_state, "h": hT.reshape(B, H, N, P)}
+        return out, {"conv": conv_state, "h": hT}
     return out
+
+
+def _gated_norm(p: dict, cfg, y, z):
+    """Mamba-2's gated RMSNorm, ``norm(y * silu(z))``, over each of the
+    ``n_groups`` groups of channels on its own (one group: all of them)."""
+    h = (y * jax.nn.silu(z)).astype(jnp.float32)
+    g = h.reshape(h.shape[:-1] + (cfg.ssm.n_groups, -1))
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, -1, keepdims=True) + cfg.norm_eps)
+    return (g.reshape(h.shape) * p["scale"].astype(jnp.float32)
+            ).astype(y.dtype)
 
 
 def mamba_cache(cfg, batch: int, dtype) -> dict:
@@ -146,7 +156,7 @@ def mamba_decode_step(p: dict, cfg, x: jax.Array, cache: dict,
     y = y.reshape(B, H, P) + p["D_skip"].astype(jnp.float32)[None, :, None] \
         * xs.astype(jnp.float32)
     y = y.reshape(B, 1, di).astype(x.dtype)
-    y = layers.rmsnorm(p["gate_norm"], y * jax.nn.silu(z), eps=cfg.norm_eps)
+    y = _gated_norm(p["gate_norm"], cfg, y, z)
     out = jnp.einsum("bsk,kd->bsd", y, p["out_proj"].astype(x.dtype))
     if advance is not None:
         keep = advance[:, None, None]

@@ -15,7 +15,9 @@ over (batch × expert) mesh axes.
 The other path is dropless and grouped (:func:`moe_apply_grouped`, taken
 with ``moe_ragged`` or ``n_held``): token–expert pairs sorted by expert
 through a grouped matmul (``lax.ragged_dot``, or the Pallas kernel of
-kernels/moe_gmm where ``use_pallas``).  With ``n_held`` set the layer is one
+kernels/moe_gmm where ``use_pallas``).  It also takes Nemotron-H's layer:
+sigmoid scores that choose by a correction bias (:func:`route`, the bias
+moved between steps by :func:`bias_step`) and relu^2 experts.  With ``n_held`` set the layer is one
 chip's share of expert parallelism: it holds ``n_held`` experts, routes over
 all ``n_experts`` and computes the part of the result its own experts give.
 Both paths take their load-balancing loss from :func:`balance_loss`.
@@ -32,7 +34,8 @@ import jax.numpy as jnp
 from repro.parallel.axes import act
 from . import layers
 
-__all__ = ["moe_init", "moe_apply", "moe_apply_grouped", "capacity"]
+__all__ = ["moe_init", "moe_apply", "moe_apply_grouped", "capacity",
+           "route", "bias_step"]
 
 
 def capacity(cfg_moe, seq_len: int) -> int:
@@ -46,19 +49,18 @@ def moe_init(key, cfg, dtype) -> dict:
     D, E, F = cfg.d_model, m.n_experts, m.d_expert
     n = m.n_held or E
     ks = jax.random.split(key, 5)
-    p = {
-        "router": layers.dense_init(ks[0], (D, E), jnp.float32,
-                                    scale=0.02),
-        "experts": {
-            "gate": _stack_init(ks[1], (n, D, F), dtype),
-            "up": _stack_init(ks[2], (n, D, F), dtype),
-            "down": _stack_init(ks[3], (n, F, D), dtype,
-                                scale=1.0 / math.sqrt(F)),
-        },
-    }
+    experts = {"up": _stack_init(ks[2], (n, D, F), dtype),
+               "down": _stack_init(ks[3], (n, F, D), dtype,
+                                   scale=1.0 / math.sqrt(F))}
+    if _expert_act(cfg) == "swiglu":
+        experts["gate"] = _stack_init(ks[1], (n, D, F), dtype)
+    p = {"router": layers.dense_init(ks[0], (D, E), jnp.float32,
+                                     scale=0.02),
+         "experts": experts}
+    if m.router == "sigmoid":
+        p["router_bias"] = jnp.zeros((E,), jnp.float32)
     if m.n_shared:
-        p["shared"] = layers.mlp_init(ks[4], cfg, dtype,
-                                      d_ff=m.n_shared * F)
+        p["shared"] = layers.mlp_init(ks[4], cfg, dtype, d_ff=m.shared_width)
     return p
 
 
@@ -122,6 +124,44 @@ def balance_loss(m, probs: jax.Array, topi: jax.Array) -> jax.Array:
 GROUP_CHUNK = 4096
 
 
+def _expert_act(cfg) -> str:
+    """The experts' MLP: relu^2 (not gated) or SiLU-gated."""
+    return "relu2" if cfg.act == "relu2" else "swiglu"
+
+
+def route(p: dict, m, xf):
+    """The router over all ``n_experts``: xf (T, D) → the chosen experts'
+    weights and ids (T, k) and the per-token distribution the balance loss
+    reads (T, E).
+
+    softmax: the top k of the softmax, renormalised where
+    ``router_norm_topk``.  sigmoid (DeepSeek-V3, Nemotron-H): the top k of
+    the sigmoid scores plus ``router_bias``, which only chooses (no
+    gradient reaches it); the weights are the chosen scores, renormalised
+    where ``router_norm_topk``; the balance loss reads the scores
+    normalised over the experts.  Either way times ``routed_scale``."""
+    k = m.top_k
+    with jax.named_scope("router"):
+        logits = jnp.einsum("td,de->te", xf.astype(jnp.float32),
+                            p["router"].astype(jnp.float32))
+        if m.router == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            _, topi = jax.lax.top_k(
+                scores + jax.lax.stop_gradient(p["router_bias"]), k)
+            topv = jnp.take_along_axis(scores, topi, axis=-1)
+            probs = scores / jnp.sum(scores, -1, keepdims=True)
+            if m.router_norm_topk:
+                topv = topv / (topv.sum(-1, keepdims=True) + 1e-20)
+        else:
+            probs = jax.nn.softmax(logits, axis=-1)
+            topv, topi = jax.lax.top_k(probs, k)  # (T, k)
+            if m.router_norm_topk:
+                topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
+        if m.routed_scale != 1.0:
+            topv = topv * m.routed_scale
+    return topv, topi, probs
+
+
 def _grouped_matmul(xs, w, sizes, cfg):
     """Rows of ``xs`` (T, D), sorted into groups of ``sizes`` rows, each
     through its own expert of ``w`` (n, D, F); rows past the groups come
@@ -153,10 +193,13 @@ def _grouped_part(p: dict, cfg, xf, topv, topi, first_expert: int):
     valid = (jnp.arange(T * k) < jnp.sum(sizes))[:, None]
     xs = jnp.where(valid, xf[order // k], 0)
     w = p["experts"]
-    g = _grouped_matmul(xs, w["gate"].astype(cd), sizes, cfg)
-    u = _grouped_matmul(xs, w["up"].astype(cd), sizes, cfg)
-    ys = _grouped_matmul(jax.nn.silu(g) * u, w["down"].astype(cd), sizes,
-                         cfg)
+    if _expert_act(cfg) == "relu2":
+        h = layers.relu2(_grouped_matmul(xs, w["up"].astype(cd), sizes, cfg))
+    else:
+        g = _grouped_matmul(xs, w["gate"].astype(cd), sizes, cfg)
+        u = _grouped_matmul(xs, w["up"].astype(cd), sizes, cfg)
+        h = jax.nn.silu(g) * u
+    ys = _grouped_matmul(h, w["down"].astype(cd), sizes, cfg)
     ys = jnp.where(valid, ys, 0)
     inv = jnp.zeros_like(order).at[order].set(
         jnp.arange(T * k, dtype=order.dtype))
@@ -176,20 +219,14 @@ def moe_apply_grouped(p: dict, cfg, x: jax.Array, first_expert: int = 0):
     grouped by expert and run through a grouped matmul, dropless: the
     buffer holds every pair, those held elsewhere sorted past the groups.
     With every expert held it equals the capacity path where that one drops
-    nothing (pinned by test).  x: (B, S, D) → (y, balance loss, tokens
-    routed to each held expert (n_held,) int32, or None where the layer
-    holds every expert)."""
+    nothing (pinned by test).  x: (B, S, D) → (y, balance loss, counters
+    as :func:`moe_apply` gives them)."""
     m = cfg.moe
     B, S, D = x.shape
     E, k = m.n_experts, m.top_k
     xf = x.reshape(-1, D)
     T = xf.shape[0]
-    logits = jnp.einsum("td,de->te", xf.astype(jnp.float32),
-                        p["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    topv, topi = jax.lax.top_k(probs, k)  # (T, k)
-    if m.router_norm_topk:
-        topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
+    topv, topi, probs = route(p, m, xf)
     nc = next(c for c in range(1, T + 1)
               if T % c == 0 and T // c <= GROUP_CHUNK or c == T)
     part = jax.checkpoint(
@@ -201,17 +238,33 @@ def moe_apply_grouped(p: dict, cfg, x: jax.Array, first_expert: int = 0):
     y = y.reshape(B, S, D).astype(x.dtype)
     aux = balance_loss(m, probs.reshape(B, S, E), topi.reshape(B, S, k))
     if m.n_shared:
-        y = y + layers.mlp(p["shared"], cfg, x, act_fn="swiglu")
-    held = jnp.sum(sizes, axis=0) if m.n_held else None
-    return act(y, "batch", "seq", "d"), aux, held
+        y = y + layers.mlp(p["shared"], cfg, x, act_fn=_expert_act(cfg))
+    counts = {}
+    if m.n_held:
+        counts["held_expert_tokens"] = jnp.sum(sizes, axis=0)
+    if m.router == "sigmoid":  # what the correction bias's step reads
+        counts["expert_load"] = jnp.sum(
+            jax.nn.one_hot(topi, E, dtype=jnp.int32), axis=(0, 1))
+    return act(y, "batch", "seq", "d"), aux, counts
 
 
 def moe_apply(p: dict, cfg, x: jax.Array):
-    """x: (B, S, D) → (y, aux_loss, tokens routed to each held expert, or
-    None where the layer holds every expert)."""
+    """x: (B, S, D) → (y, aux_loss, counters): the counters are
+    ``held_expert_tokens`` (n_held,), the tokens routed to each held
+    expert, where the layer holds a share, and ``expert_load`` (E,), the
+    tokens that chose each expert, where the router has a correction
+    bias."""
     if cfg.moe.n_held or cfg.moe_ragged:
         return moe_apply_grouped(p, cfg, x)
+    # the capacity path: the softmax router and SiLU-gated experts only
     m = cfg.moe
+    if m.router != "softmax" or m.routed_scale != 1.0 \
+            or _expert_act(cfg) != "swiglu":
+        raise ValueError(
+            f"the capacity path takes the softmax router, routed_scale 1 "
+            f"and SiLU-gated experts only (router {m.router!r}, "
+            f"routed_scale {m.routed_scale}, experts {_expert_act(cfg)!r}); "
+            f"set moe.n_held or moe_ragged for the grouped path")
     B, S, D = x.shape
     E, k = m.n_experts, m.top_k
     C = capacity(m, S)
@@ -239,4 +292,13 @@ def moe_apply(p: dict, cfg, x: jax.Array):
     y = jnp.einsum("bsec,ebcd->bsd", combine.astype(cd), ye)
     if m.n_shared:
         y = y + layers.mlp(p["shared"], cfg, x, act_fn="swiglu")
-    return act(y, "batch", "seq", "d"), aux, None
+    return act(y, "batch", "seq", "d"), aux, {}
+
+
+def bias_step(bias: jax.Array, load: jax.Array, rate: float) -> jax.Array:
+    """The auxiliary-loss-free balancing step of a router's correction
+    bias (DeepSeek-V3): ``b_i + rate * sign(mean(load) - load_i)``, each
+    row of ``load`` (..., E) the tokens that chose each expert of a layer
+    in one step.  Outside the gradient and the optimizer."""
+    load = load.astype(jnp.float32)
+    return bias + rate * jnp.sign(jnp.mean(load, -1, keepdims=True) - load)

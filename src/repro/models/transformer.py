@@ -4,7 +4,9 @@ A model is a sequence of *segments* — homogeneous runs of one block kind that
 are parameter-stacked and executed with ``lax.scan`` (small HLO, fast
 compile, remat-friendly), mirroring MaxText.  zamba2's *shared* attention
 block (one parameter set applied every ``period`` layers) sits between
-mamba segments; its weights live once in the param tree.
+mamba segments; its weights live once in the param tree.  A config with a
+``pattern`` (Nemotron-H) has one mixer per layer, each block
+``x + mixer(norm(x))``: its segments are the pattern's runs.
 
 Entry points::
 
@@ -35,9 +37,23 @@ __all__ = ["structure", "init_params", "forward", "loss_fn", "init_cache",
 # segment structure per family
 # --------------------------------------------------------------------------
 
+# a pattern's letters and the single-mixer blocks they stand for
+PATTERN_KINDS = {"M": "mamba", "E": "moe", "*": "attn_only"}
+
+
 def structure(cfg) -> list[tuple[str, int]]:
     """Returns [(block_kind, count), ...] covering cfg.n_layers."""
     L = cfg.n_layers
+    if cfg.pattern:
+        assert len(cfg.pattern) == L, (cfg.pattern, L)
+        segs = []
+        for c in cfg.pattern:
+            kind = PATTERN_KINDS[c]
+            if segs and segs[-1][0] == kind:
+                segs[-1] = (kind, segs[-1][1] + 1)
+            else:
+                segs.append((kind, 1))
+        return segs
     if cfg.family in ("dense", "vlm"):
         return [("attn", L)]
     if cfg.family == "moe":
@@ -70,6 +86,12 @@ def n_shared_applications(cfg) -> int:
 
 def _block_init(key, cfg, dtype, kind: str) -> dict:
     ninit, _ = layers.norm(cfg.norm)
+    if kind == "attn_only":
+        return {"norm1": ninit(cfg.d_model, dtype),
+                "attn": layers.attention_init(key, cfg, dtype)}
+    if kind == "moe":
+        return {"norm1": ninit(cfg.d_model, dtype),
+                "moe": moe.moe_init(key, cfg, dtype)}
     if kind in ("attn", "attn_moe", "shared_attn"):
         ks = jax.random.split(key, 2)
         attn_init = layers.mla_init if cfg.mla else layers.attention_init
@@ -96,12 +118,20 @@ def _block_init(key, cfg, dtype, kind: str) -> dict:
 
 def _block_apply(p: dict, cfg, x, positions, kind: str, cache=None,
                  advance=None):
-    """Returns (x, aux, new_cache, held): ``held`` is the tokens routed to
-    each held expert of an expert layer that holds a share (else None)."""
+    """Returns (x, aux, new_cache, counts): ``counts`` are an expert
+    layer's counters (:func:`moe.moe_apply`), else empty."""
     _, napply = layers.norm(cfg.norm)
     nfn = functools.partial(napply, eps=cfg.norm_eps)
     aux = jnp.asarray(0.0, jnp.float32)
-    held = None
+    counts = {}
+    if kind == "moe":
+        f, aux, counts = moe.moe_apply(p["moe"], cfg, nfn(p["norm1"], x))
+        return x + f, aux, cache, counts
+    if kind == "attn_only":
+        a_out, new_cache = layers.attention(
+            p["attn"], cfg, nfn(p["norm1"], x), positions=positions,
+            causal=True, cache=cache, advance=advance)
+        return x + a_out, aux, new_cache, counts
     if kind in ("attn", "attn_moe", "shared_attn"):
         h = nfn(p["norm1"], x)
         if cfg.mla:
@@ -115,29 +145,32 @@ def _block_apply(p: dict, cfg, x, positions, kind: str, cache=None,
         x = x + a_out
         h2 = nfn(p["norm2"], x)
         if kind == "attn_moe":
-            f, aux, held = moe.moe_apply(p["moe"], cfg, h2)
+            f, aux, counts = moe.moe_apply(p["moe"], cfg, h2)
         else:
             f = layers.mlp(p["mlp"], cfg, h2)
-        return x + f, aux, new_cache, held
+        return x + f, aux, new_cache, counts
     if kind == "mamba":
         h = nfn(p["norm1"], x)
         if cache is None:
-            return x + mamba.mamba_apply(p["mamba"], cfg, h), aux, None, None
+            return x + mamba.mamba_apply(p["mamba"], cfg, h), aux, None, \
+                counts
         if h.shape[1] > 1:  # prefill: full scan, then hand over the state
             out, new_cache = mamba.mamba_apply(p["mamba"], cfg, h,
                                                return_state=True)
         else:
             out, new_cache = mamba.mamba_decode_step(p["mamba"], cfg, h,
                                                      cache, advance=advance)
-        return x + out, aux, new_cache, None
+        return x + out, aux, new_cache, counts
     raise ValueError(kind)
 
 
 def _block_cache(cfg, kind: str, batch: int, max_len: int, dtype):
-    if kind in ("attn", "attn_moe", "shared_attn"):
+    if kind in ("attn", "attn_moe", "shared_attn", "attn_only"):
         return layers.attention_cache(cfg, batch, max_len, dtype)
     if kind == "mamba":
         return mamba.mamba_cache(cfg, batch, dtype)
+    if kind == "moe":
+        return {}
     raise ValueError(kind)
 
 
@@ -197,39 +230,40 @@ def _scan_segment(cfg, seg_params, x, positions, kind: str):
 
     def body(carry, lp):
         h, aux = carry
-        h2, a, _, held = _block_apply(lp, cfg, h, positions, kind)
-        return (h2, aux + a), held
+        with jax.named_scope(kind):  # names the block's ops in a profile
+            h2, a, _, counts = _block_apply(lp, cfg, h, positions, kind)
+        return (h2, aux + a), counts
 
     if cfg.remat == "full":
         body = jax.checkpoint(body, prevent_cse=False)
     carry = (x, jnp.asarray(0.0, jnp.float32))
-    (x, aux), held = jax.lax.scan(body, carry, seg_params)
-    return x, aux, held
+    (x, aux), counts = jax.lax.scan(body, carry, seg_params)
+    return x, aux, counts
 
 
 def hidden_states(cfg, params, tokens, *, positions=None,
                   input_embeds=None):
     """Backbone up to (and including) the final norm: (B,S,D), aux, and the
-    tokens routed to each held expert, (expert layers, n_held) int32, where
-    the expert layers hold a share (else None)."""
+    expert layers' counters (:func:`moe.moe_apply`), each stacked over the
+    expert layers in order: (expert layers, n) int32."""
     x = (layers.embed(params["embedding"], cfg, tokens)
          if input_embeds is None else input_embeds)
     pos = _positions(cfg, tokens) if positions is None else positions
     aux_total = jnp.asarray(0.0, jnp.float32)
-    helds = []
+    counts: dict[str, list] = {}
     for (kind, count), seg_p in zip(structure(cfg), params["segments"]):
         if kind == "shared_attn":
             x, a, _, _ = _block_apply(params["shared_block"], cfg, x, pos,
                                       kind)
             aux_total = aux_total + a
         else:
-            x, a, held = _scan_segment(cfg, seg_p, x, pos, kind)
+            x, a, c = _scan_segment(cfg, seg_p, x, pos, kind)
             aux_total = aux_total + a
-            if held is not None:
-                helds.append(held)
+            for name, v in c.items():
+                counts.setdefault(name, []).append(v)
     _, napply = layers.norm(cfg.norm)
     x = napply(params["final_norm"], x, eps=cfg.norm_eps)
-    return x, aux_total, (jnp.concatenate(helds) if helds else None)
+    return x, aux_total, {k: jnp.concatenate(v) for k, v in counts.items()}
 
 
 def forward(cfg, params, tokens, *, positions=None, input_embeds=None):
@@ -274,12 +308,15 @@ def _nll_chunked(cfg, params, hidden, labels):
 def loss_fn(cfg, params, batch, *, aux_weight: Optional[float] = None):
     """batch: {"tokens": (B,S), "labels": (B,S)} → (loss, metrics).
 
-    ``aux_weight`` defaults to the config's balance-loss weight.  Where the
-    expert layers hold a share, ``metrics["held_expert_tokens"]`` counts the
-    tokens routed to each held expert, (expert layers, n_held) int32."""
+    ``aux_weight`` defaults to the config's balance-loss weight.  The
+    metrics hold the expert layers' counters: ``held_expert_tokens``, the
+    tokens routed to each held expert, (expert layers, n_held) int32, where
+    the expert layers hold a share; ``expert_load``, the tokens that chose
+    each expert, (expert layers, n_experts), where the router has a
+    correction bias."""
     if aux_weight is None:
         aux_weight = cfg.moe.aux_weight if cfg.moe else 0.0
-    hidden, aux, held = hidden_states(cfg, params, batch["tokens"])
+    hidden, aux, counts = hidden_states(cfg, params, batch["tokens"])
     labels = batch["labels"]
     B, S = labels.shape
     if cfg.loss_chunk and S > cfg.loss_chunk:
@@ -289,9 +326,7 @@ def loss_fn(cfg, params, batch, *, aux_weight: Optional[float] = None):
     nll = total / (B * S)
     loss = nll + aux_weight * aux
     metrics = {"nll": nll, "aux": aux,
-               "perplexity": jnp.exp(jnp.minimum(nll, 20.0))}
-    if held is not None:
-        metrics["held_expert_tokens"] = held
+               "perplexity": jnp.exp(jnp.minimum(nll, 20.0)), **counts}
     return loss, metrics
 
 

@@ -12,6 +12,7 @@ one.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any, Callable, Optional
 
@@ -22,7 +23,7 @@ from repro.core import (AnyFanOne, Collect, Emit, Network, OneFanAny, Worker,
                         build)
 from repro.core import trace as _trace
 from repro.core.stream import stack_microbatches
-from repro.models import Model
+from repro.models import Model, moe
 from .optimizer import AdamW
 
 __all__ = ["TrainState", "make_train_step", "as_network", "train"]
@@ -41,7 +42,9 @@ def make_train_step(model: Model, opt: AdamW, *,
 
     ``grad_accum > 1`` splits the global batch into microbatches along the
     leading axis and accumulates grads in f32 with a lax.scan (memory lever).
-    Jitted, the step is the device program ``jit_train_step``.
+    Jitted, the step is the device program ``jit_train_step``.  The
+    routers' correction biases stay as they are: no gradient reaches them,
+    and the optimizer's step is not taken (:func:`train` moves them).
     """
 
     def loss(params, batch):
@@ -71,14 +74,47 @@ def make_train_step(model: Model, opt: AdamW, *,
             grads = jax.tree_util.tree_map(lambda x: x / grad_accum, g_sum)
             l = l_sum / grad_accum
             metrics = jax.tree_util.tree_map(lambda x: x[-1], ms)
-            if "held_expert_tokens" in ms:
-                metrics["held_expert_tokens"] = jnp.sum(
-                    ms["held_expert_tokens"], axis=0)
+            for name in COUNTERS:
+                if name in ms:
+                    metrics[name] = jnp.sum(ms[name], axis=0)
         new_params, new_opt, stats = opt.update(grads, opt_state, params)
+        new_params = jax.tree_util.tree_map_with_path(
+            lambda path, new, old: old if _is_router_bias(path) else new,
+            new_params, params)
         metrics = dict(metrics, loss=l, **stats)
         return new_params, new_opt, metrics
 
     return train_step
+
+
+# the expert layers' counters a step returns (models.moe.moe_apply)
+COUNTERS = ("held_expert_tokens", "expert_load")
+
+
+def _is_router_bias(path) -> bool:
+    return getattr(path[-1], "key", None) == "router_bias"
+
+
+def _bias_update(params, load, rate: float):
+    """``params`` with each router correction bias moved a step
+    (:func:`moe.bias_step`) by ``load``, the step's ``expert_load`` counter:
+    its rows follow the expert layers in order, as the stacked biases do."""
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    at = [i for i, (path, _) in enumerate(flat) if _is_router_bias(path)]
+    new = _bias_steps([flat[i][1] for i in at], load, rate)
+    leaves = [leaf for _, leaf in flat]
+    for i, b in zip(at, new):
+        leaves[i] = b
+    return treedef.unflatten(leaves)
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _bias_steps(biases, load, rate):
+    out, row = [], 0
+    for b in biases:  # (layers of the segment, n_experts)
+        out.append(moe.bias_step(b, load[row:row + b.shape[0]], rate))
+        row += b.shape[0]
+    return out
 
 
 def as_network(model: Model, opt: AdamW, *, grad_accum: int = 1,
@@ -122,7 +158,11 @@ def train(model: Model, source, *, steps: int, opt: Optional[AdamW] = None,
     ``train_step`` span from the step's dispatch to its loss on the host
     (the one wait the loop adds, and only when recording), and, where the
     expert layers hold a share, a ``held_expert_tokens`` counter: the tokens
-    routed to each held expert in the step, a list per expert layer.
+    routed to each held expert in the step, a list per expert layer; and
+    where the routers have a correction bias, an ``expert_load`` counter:
+    the tokens that chose each expert in the step, a list per expert layer.
+    That count moves the biases after each step (``moe.bias_step``, at the
+    config's ``bias_rate``), outside the gradient and the optimizer.
 
     Returns {"params", "opt_state", "history", "step", "step_fn"}: ``step``
     is one past the last step run, ``step_fn`` the jitted step (it donates
@@ -147,12 +187,16 @@ def train(model: Model, source, *, steps: int, opt: Optional[AdamW] = None,
                 batch = shard_batch(batch, mesh)
         with rec.span("train_step", "train", step=i):
             params, opt_state, metrics = step_fn(params, opt_state, batch)
+            counts = {k: metrics.pop(k) for k in COUNTERS if k in metrics}
+            if "expert_load" in counts:
+                params = _bias_update(params, counts["expert_load"],
+                                      model.cfg.moe.bias_rate)
             if rec.enabled:
                 float(metrics["loss"])
-        held = metrics.pop("held_expert_tokens", None)
-        if held is not None and rec.enabled:
-            rec.counter("held_expert_tokens", jax.device_get(held).tolist(),
-                        "train", step=i)
+        if rec.enabled:
+            for name, v in counts.items():
+                rec.counter(name, jax.device_get(v).tolist(), "train",
+                            step=i)
         stop = on_step is not None and on_step(i, params, opt_state,
                                                metrics)
         if ckpt_every and checkpointer is not None \

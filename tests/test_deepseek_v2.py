@@ -161,7 +161,7 @@ def test_eight_shares_sum_to_the_whole_layer():
             np.testing.assert_allclose(np.asarray(y), np.asarray(part),
                                        rtol=1e-4, atol=1e-5)
             total = total + y
-            counts = counts + int(jnp.sum(n))
+            counts = counts + int(jnp.sum(n["held_expert_tokens"]))
     np.testing.assert_allclose(np.asarray(total), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
     assert counts == 2 * 32 * 6          # every token's 6 pairs, once
@@ -203,7 +203,8 @@ def test_held_path_is_chunked_over_tokens(monkeypatch):
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y4), rtol=1e-5,
                                atol=1e-6)
     assert float(a1) == pytest.approx(float(a4))
-    np.testing.assert_array_equal(np.asarray(n1), np.asarray(n4))
+    np.testing.assert_array_equal(np.asarray(n1["held_expert_tokens"]),
+                                  np.asarray(n4["held_expert_tokens"]))
 
 
 @pytest.mark.parametrize("seq_aux", [False, True])
@@ -219,7 +220,7 @@ def test_every_path_takes_the_same_balance_loss(key, seq_aux):
     h = jax.random.normal(jax.random.PRNGKey(3), (2, 24, cfg.d_model))
     y1, a1, n1 = moe.moe_apply(p, cfg, h)
     y2, a2, n2 = moe.moe_apply(p, grouped, h)
-    assert n1 is None and n2 is None
+    assert n1 == {} and n2 == {}
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), rtol=1e-4,
                                atol=1e-5)
     assert float(a1) == pytest.approx(float(a2), rel=1e-6)
@@ -245,7 +246,8 @@ def test_grouped_path_pallas_kernel_equals_ragged_dot():
         p, dataclasses.replace(cfg, use_pallas=True), h, first_expert=1)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), rtol=1e-4,
                                atol=1e-5)
-    np.testing.assert_array_equal(np.asarray(n1), np.asarray(n2))
+    np.testing.assert_array_equal(np.asarray(n1["held_expert_tokens"]),
+                                  np.asarray(n2["held_expert_tokens"]))
     assert float(a1) == float(a2)
 
 

@@ -185,11 +185,54 @@ class TestSSD:
         B = jnp.asarray(rng.normal(size=(BH, S, N)).astype(np.float32)) * .3
         C = jnp.asarray(rng.normal(size=(BH, S, N)).astype(np.float32)) * .3
         y0, h0 = ssd_ref.ssd_naive(x, dt, A, B, C)
-        y1, h1 = ssd_ref.ssd_chunked(x, dt, A, B, C, chunk=chunk)
-        np.testing.assert_allclose(np.asarray(y0), np.asarray(y1),
+        # one sequence of BH heads, a state group each
+        heads = lambda a: jnp.moveaxis(a, 0, 1)[None]  # noqa: E731
+        y1, h1 = ssd_ref.ssd_chunked(heads(x), heads(dt), A, heads(B),
+                                     heads(C), chunk=chunk)
+        np.testing.assert_allclose(np.asarray(heads(y0)),
+                                   np.asarray(y1), rtol=1e-4, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(h0), np.asarray(h1[0]),
                                    rtol=1e-4, atol=1e-5)
-        np.testing.assert_allclose(np.asarray(h0), np.asarray(h1),
-                                   rtol=1e-4, atol=1e-5)
+
+    @pytest.mark.parametrize("use_pallas", [False, True])
+    def test_state_groups_vs_naive(self, rng, use_pallas):
+        """1 < G < H: head h reads state group h // (H/G), in the chunked
+        form (C.B once per group) and in the kernel's folded form."""
+        b, S, H, P, G, N = 2, 32, 8, 4, 2, 8
+        x = jnp.asarray(rng.normal(size=(b, S, H, P)).astype(np.float32))
+        dt = jnp.asarray(rng.random((b, S, H)).astype(np.float32)) * 0.2
+        A = -jnp.asarray(rng.random(H).astype(np.float32)) - 0.1
+        B = jnp.asarray(rng.normal(size=(b, S, G, N)).astype(np.float32)) * .3
+        C = jnp.asarray(rng.normal(size=(b, S, G, N)).astype(np.float32)) * .3
+        y = ssd_ops.ssd(x, dt, A, B, C, chunk=8, use_pallas=use_pallas)
+        fold = lambda a: jnp.moveaxis(a, 2, 1).reshape(  # noqa: E731
+            (b * H, S) + a.shape[3:])
+        y0, _ = ssd_ref.ssd_naive(
+            fold(x), fold(dt), jnp.tile(A, b),
+            fold(jnp.repeat(B, H // G, axis=2)),
+            fold(jnp.repeat(C, H // G, axis=2)))
+        np.testing.assert_allclose(
+            np.asarray(y), np.moveaxis(np.asarray(y0).reshape(b, H, S, P),
+                                       1, 2), rtol=1e-4, atol=1e-5)
+
+    def test_gradient_finite_past_float32_exp_range(self, rng):
+        """A chunk whose summed log-decay passes 88 (where exp overflows
+        float32): the exponent is masked before exp, so the gradient stays
+        finite (masking after exp gave inf * 0 in the backward)."""
+        b, S, H, P, N = 1, 32, 2, 4, 4
+        x = jnp.asarray(rng.normal(size=(b, S, H, P)).astype(np.float32))
+        dt = jnp.full((b, S, H), 2.0, jnp.float32)
+        A = jnp.asarray([-8.0, -1.0], jnp.float32)  # 16 a step: 256 a chunk
+        B = jnp.asarray(rng.normal(size=(b, S, 1, N)).astype(np.float32))
+        C = jnp.asarray(rng.normal(size=(b, S, 1, N)).astype(np.float32))
+
+        def loss(dt, A, B, C):
+            return jnp.sum(ssd_ops.ssd(x, dt, A, B, C, chunk=16) ** 2)
+
+        grads = jax.grad(loss, argnums=(0, 1, 2, 3))(dt, A, B, C)
+        for g in grads:
+            assert np.isfinite(np.asarray(g)).all()
+        assert np.isfinite(float(loss(dt, A, B, C)))
 
     @pytest.mark.parametrize("chunk", [16, 32])
     def test_pallas_vs_naive(self, rng, chunk):

@@ -107,6 +107,17 @@ class TestFamilySpecific:
         assert sum(c for k, c in segs if k == "mamba") == 38
         assert sum(1 for k, _ in segs if k == "shared_attn") == 6
 
+    @pytest.mark.parametrize("arch, heads, d_inner", [
+        ("mamba2-2.7b", 80, 5120), ("zamba2-1.2b", 64, 4096)])
+    def test_mamba_heads_unchanged(self, arch, heads, d_inner):
+        """Mamba heads given explicitly (Nemotron-H) leave the families
+        that derive them from ``expand * d_model`` as they were."""
+        from repro.models import mamba
+        cfg = get_config(arch)
+        assert cfg.ssm.heads == 0
+        assert cfg.ssm.n_heads(cfg.d_model) == heads
+        assert mamba._dims(cfg)[:2] == (d_inner, heads)
+
     def test_mamba_attention_free(self, key):
         from repro.models.transformer import structure
         cfg = get_config("mamba2-2.7b")
